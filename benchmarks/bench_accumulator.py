@@ -4,7 +4,8 @@ sparse wire format.
 Validates the paper's claim three ways:
 1. host accumulator: exact wire-traffic accounting per mode (sparse figures
    derived from the actual pair-array lengths);
-2. SPMD lowering on an 8-device mesh: per-device collective bytes parsed from
+2. SPMD lowering on a mesh over every device (8 virtual ones on a CPU
+   host, the chips on a TPU host): per-device collective bytes parsed from
    the compiled HLO — gather_all ≈ N·V vs reduce_scatter ≈ 2·V per device —
    plus wall time per accumulate call;
 3. dense-vs-sparse-vs-auto sweep over nnz density: which wire format the auto
@@ -16,9 +17,6 @@ accumulator``).
 """
 
 import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -36,6 +34,7 @@ from repro.core import AccumMode, DAddAccumulator, GlobalStore, accumulate, shar
 from repro.core.sparse import blocked_topk_sparsify, pair_capacity
 from repro.launch.mesh import make_host_mesh
 from repro.utils.hlo import collective_bytes_from_hlo
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS = {}
 
@@ -73,12 +72,13 @@ def host_layer():
 
 
 def spmd_layer():
-    mesh = make_host_mesh(data=8)
+    n = len(jax.devices())
+    mesh = make_host_mesh(data=n)
     V = 1 << 16
-    x = jnp.arange(8 * V, dtype=jnp.float32).reshape(8, V)
+    x = jnp.arange(n * V, dtype=jnp.float32).reshape(n, V)
     # sparse input (each shard has <= k nonzeros) for the sparse/auto rows
-    xs = np.zeros((8, V), np.float32)
-    for i in range(8):
+    xs = np.zeros((n, V), np.float32)
+    for i in range(n):
         xs[i, (np.arange(5) * 1024 + i * 7) % V] = float(i + 1)  # ≤1 nnz per block
     xs = jnp.asarray(xs)
 
@@ -150,6 +150,10 @@ def sparsity_sweep():
 
 
 def main():
+    enable_compile_cache()
+    # the SPMD layer spans every device: 8 virtual ones on a CPU host (the
+    # option only shapes the CPU backend), the chips themselves on a TPU host
+    jax.config.update("jax_num_cpu_devices", 8)
     host_layer()
     spmd_layer()
     sparsity_sweep()

@@ -19,6 +19,7 @@ import numpy as np
 from benchmarks.common import emit, timeit
 from repro.analytics import kmeans, logreg, nmf, pagerank
 from repro.data import kmeans_dataset, logreg_dataset, nmf_dataset, powerlaw_graph
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def bench_logreg():
@@ -86,6 +87,7 @@ def bench_spmd_scan():
 
 
 def main():
+    enable_compile_cache()
     bench_logreg()
     bench_kmeans()
     bench_nmf()

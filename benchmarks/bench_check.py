@@ -26,6 +26,7 @@ from benchmarks.common import emit, write_bench
 from repro.check import NULL_CHECKER, Checker
 from repro.check import checker as stepcheck
 from repro.core import DSMCache, GlobalStore, Session
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _rw_mix_once(state: str, n_threads=8, n_names=64, ops_per_thread=120,
@@ -98,6 +99,7 @@ def _logreg_fit(state: str, repeats=5):
 
 
 def main():
+    enable_compile_cache()
     assert stepcheck.armed_count() == 0
     results = {"workload_rw": {"threads": 8, "shards": 8, "names": 64,
                                "ops_per_thread": 120, "vector_len": 262144},

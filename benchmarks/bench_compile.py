@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from benchmarks.common import emit, write_bench
 from repro.core import Session
 from repro.core.compat import cost_analysis
+from repro.utils.compile_cache import enable_compile_cache
 
 ITERS_SWEEP = (2, 32, 256)
 N_ROWS, N_FEATURES = 256, 64
@@ -41,6 +42,7 @@ def _program(sess, grad, iters: int):
 
 
 def main():
+    enable_compile_cache()
     xs = jnp.ones((N_ROWS, N_FEATURES), jnp.float32)
     ys = jnp.ones((N_ROWS,), jnp.float32)
     rows = {}

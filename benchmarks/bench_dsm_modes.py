@@ -25,9 +25,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from benchmarks.common import emit, timeit, write_bench
 from repro.core import DSMCache, GlobalStore, pack_spec, pack_tree, unpack_tree
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     n_leaves, leaf = 200, 512
     tree = {f"w{i}": jnp.full((leaf,), float(i)) for i in range(n_leaves)}
 

@@ -18,9 +18,11 @@ from benchmarks.common import emit
 from repro.analytics import kmeans
 from repro.data import kmeans_dataset, partition_rows
 from repro.ft import plan_recovery
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     x, _, _ = kmeans_dataset(40000, 32, 16, seed=0)
     n_nodes, tpn = 4, 4
     n_threads = n_nodes * tpn

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit, timeit, write_bench
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS = {}
 
@@ -116,6 +117,7 @@ def owner_memo_bench():
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
 
     from repro.kernels.flash_attention.ops import flash_attention as fa

@@ -30,6 +30,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from benchmarks.common import emit, write_bench
 from repro.core import DSMCache, GlobalStore
+from repro.utils.compile_cache import enable_compile_cache
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -190,6 +191,7 @@ def rebalance_under_load(incremental, n_threads=4, n_names=2048,
 
 
 def main():
+    enable_compile_cache()
     # a 0.5ms GIL quantum keeps scheduler starvation out of the pause
     # measurement — what remains is actual lock blocking
     sys.setswitchinterval(0.0005)

@@ -24,6 +24,7 @@ from benchmarks.bench_dsm_modes import _mixed_workload
 from benchmarks.common import emit, write_bench
 from repro.core import DSMCache, GlobalStore, Session, telemetry
 from repro.core.telemetry import NULL_TRACER, Tracer
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _rw_mix_once(state: str, n_threads=8, n_names=64, ops_per_thread=120,
@@ -93,6 +94,7 @@ def _logreg_fit(state: str, repeats=5):
 
 
 def main():
+    enable_compile_cache()
     assert telemetry.armed_count() == 0
     results = {"workload_rw": {"threads": 8, "shards": 8, "names": 64,
                                "ops_per_thread": 120, "vector_len": 262144},

@@ -21,9 +21,11 @@ from repro.analytics import kmeans
 from repro.core import Session
 from repro.data import kmeans_dataset
 from repro.ft import HeartbeatMonitor, save_checkpoint, restore_checkpoint, session_recovery
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     x, _, _ = kmeans_dataset(4000, 16, 8, seed=0)
     n_nodes, tpn = 4, 2
 

@@ -19,9 +19,11 @@ import numpy as np
 from repro.analytics import logreg
 from repro.core import AccumMode
 from repro.data import logreg_dataset
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     x, y, theta_true = logreg_dataset(n_rows=2000, n_features=64, seed=0)
 
     ref = logreg.fit_reference(x, y, iters=20, lr=1e-3)

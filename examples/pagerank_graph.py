@@ -15,9 +15,11 @@ import numpy as np
 from repro.analytics import pagerank
 from repro.core import AccumMode
 from repro.data import powerlaw_graph
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     n_vertices = 2000
     edges = powerlaw_graph(n_vertices, avg_degree=8, seed=0)
     print(f"graph: {n_vertices} vertices, {edges.shape[0]} edges")

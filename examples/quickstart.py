@@ -22,9 +22,11 @@ import numpy as np
 from repro.analytics import logreg
 from repro.core import AccumMode, Session
 from repro.data import logreg_dataset
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # 1. the Table-1 facade: DSM declaration through handles (paper §4.1)
     sess = Session(backend="host", n_nodes=2, threads_per_node=2)
     step_size = sess.def_global("step_size", 1e-3)

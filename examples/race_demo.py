@@ -20,6 +20,7 @@ disjoint round via a DBarrier hand-off — and shows the checker stays silent.
 import jax.numpy as jnp
 
 from repro.core import Session
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def racy():
@@ -66,6 +67,7 @@ def synchronized():
 
 
 def main():
+    enable_compile_cache()
     racy_findings = racy()
     clean_findings = synchronized()
     assert racy_findings, "the seeded race must be detected"

@@ -10,9 +10,11 @@ every decode step, never invalidated because the owner is the only writer.
 import argparse
 
 from repro.launch.serve import serve
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
     ap.add_argument("--batch", type=int, default=8)

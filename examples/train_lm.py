@@ -14,6 +14,7 @@ from repro.configs import get_arch
 from repro.launch.train import train as _train
 import repro.launch.train as train_mod
 from repro.configs.base import ArchConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 # ~100M params: 12 × (d512 swiglu-2048 blocks, 8 heads) + 32k vocab embed/head
 LM100M = ArchConfig(
@@ -24,6 +25,7 @@ LM100M = ArchConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--batch", type=int, default=8)

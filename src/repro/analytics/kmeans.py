@@ -20,16 +20,22 @@ from repro.core import AccumMode, Session
 from repro.core.session import SpmdBackend, deprecated_entry
 
 
+# f32 matmuls at full precision: a TPU's default rounds their inputs to bf16,
+# which moves points across cluster boundaries and the centres with them
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
 @jax.jit
 def _assign(points, centers):
     d2 = (jnp.sum(points**2, axis=1, keepdims=True)
-          - 2.0 * points @ centers.T + jnp.sum(centers**2, axis=1)[None])
+          - 2.0 * jnp.matmul(points, centers.T, precision=HIGHEST)
+          + jnp.sum(centers**2, axis=1)[None])
     return jnp.argmin(d2, axis=1), jnp.min(d2, axis=1)
 
 
 def _partials(points, assign, k):
     onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32)      # (n, k)
-    sums = onehot.T @ points                                    # (k, d)
+    sums = jnp.matmul(onehot.T, points, precision=HIGHEST)      # (k, d)
     counts = jnp.sum(onehot, axis=0)                            # (k,)
     return sums, counts
 

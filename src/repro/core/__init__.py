@@ -10,7 +10,7 @@
 - :mod:`repro.core.threads` - DThread pool + shard_map SPMD adapter
 - :mod:`repro.core.addressing` - the 64-bit DSM address space
 - :mod:`repro.core.telemetry` - step.trace: spans/counters/histograms + export
-- :mod:`repro.core.compat` - shims over moving JAX APIs (shard_map, meshes)
+- :mod:`repro.core.compat` - mesh / shard_map helpers with the repo's defaults
 
 Most programs need only :class:`~repro.core.session.Session`: it owns the
 store, cache, thread pool, sync controller and accumulator registry, and the

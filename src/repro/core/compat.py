@@ -1,10 +1,7 @@
-"""Version-compatibility shims over the moving parts of the JAX API.
+"""Thin helpers over the JAX APIs that build meshes and shard_maps.
 
-The repo targets current JAX (``jax.shard_map``, ``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=...)``) but must also run on older releases
-where ``shard_map`` still lives in ``jax.experimental`` (with ``check_rep``
-instead of ``check_vma``) and meshes have no axis types.  Everything that
-builds a mesh or a shard_map goes through these two helpers.
+Everything that builds a mesh or a shard_map goes through these helpers, so
+the repo's defaults (Auto axis types, ``check_vma`` off) live in one place.
 """
 
 from __future__ import annotations
@@ -12,63 +9,33 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
-
-try:  # jax >= 0.5-ish
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old.
-
-    ``check_vma`` maps onto the old API's ``check_rep`` (same role: verify
-    replication/varying-axis annotations; both default off here because the
-    accumulator's collectives produce deliberately replicated outputs).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with varying-axis checks off by default: the
+    accumulator's collectives produce deliberately replicated outputs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 def axis_size(axis) -> int:
-    """Static size of a named mesh axis (or tuple of axes) inside shard_map.
-
-    New JAX exposes ``jax.lax.axis_size``; on older releases ``psum(1, axis)``
-    is constant-folded to the same static integer.
-    """
+    """Static size of a named mesh axis (or tuple of axes) inside shard_map."""
     axes = axis if isinstance(axis, (tuple, list)) else (axis,)
-    if hasattr(jax.lax, "axis_size"):
-        n = 1
-        for a in axes:
-            n *= jax.lax.axis_size(a)
-        return n
-    return jax.lax.psum(1, tuple(axes))
+    n = 1
+    for a in axes:
+        n *= jax.lax.axis_size(a)
+    return n
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalised ``compiled.cost_analysis()``: one flat dict of metrics.
-
-    Newer JAX returns the dict directly; older releases return a one-element
-    list of dicts (one per computation); some backends return ``None`` or
-    raise.  Callers always get a plain dict (possibly empty).
-    """
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # pragma: no cover - backend-dependent
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def make_mesh(shape: Sequence[int], names: Sequence[str], devices=None):
-    """``jax.make_mesh`` with Auto axis types where the installed jax has them."""
+    """``jax.make_mesh`` with Auto axis types."""
     shape, names = tuple(shape), tuple(names)
-    if _AxisType is not None:
-        return jax.make_mesh(shape, names, devices=devices,
-                             axis_types=(_AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, names, devices=devices)
+    return jax.make_mesh(shape, names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))

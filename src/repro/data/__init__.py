@@ -6,10 +6,11 @@ from repro.data.synthetic import (
     logreg_dataset,
     nmf_dataset,
     powerlaw_graph,
+    rmat_graph,
 )
 
 __all__ = [
     "LMDataPipeline", "Prefetcher", "partition_rows", "shard_batch",
     "SyntheticLM", "kmeans_dataset", "lm_batch", "logreg_dataset",
-    "nmf_dataset", "powerlaw_graph",
+    "nmf_dataset", "powerlaw_graph", "rmat_graph",
 ]

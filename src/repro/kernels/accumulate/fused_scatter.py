@@ -28,28 +28,35 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bitonic import bitonic_sort_desc
+from repro.kernels.bitonic import LANES, bitonic_sort_desc, block_rows, flat_position
 
 
 def _fused_scatter_kernel(x_ref, o_ref, *, per_block: int, block_eff: int,
                           total: int):
-    j = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)                       # (N, block_eff)
-    base = j * block_eff
-    pos = base + jax.lax.iota(jnp.int32, block_eff)
-    valid = pos < total
-    mag = jnp.where(valid[None, :], jnp.abs(x), -1.0)
+    x = x_ref[...].astype(jnp.float32)                       # (N, rows, 128)
+    col = flat_position(x.shape)
+    base = pl.program_id(0) * block_eff
+    valid = (col < block_eff) & (base + col < total)
+    mag = jnp.where(valid, jnp.abs(x), -1.0)
     if per_block < block_eff:
-        idx = jnp.broadcast_to(pos[None, :], mag.shape)
-        sorted_mag, sorted_idx = bitonic_sort_desc(mag, idx)
-        thr_mag = sorted_mag[:, per_block - 1][:, None]      # (N, 1)
-        thr_idx = sorted_idx[:, per_block - 1][:, None]
+        sorted_mag, sorted_col = bitonic_sort_desc(mag, col)
+        # each row's per_block-th entry in (mag desc, idx asc) order is its
+        # selection threshold; pick it out with a one-hot max per row
+        at = col == per_block - 1
+
+        def threshold(t, fill):
+            t = jnp.where(at, t, fill)
+            return jnp.max(jnp.max(t, axis=2, keepdims=True), axis=1,
+                           keepdims=True)                    # (N, 1, 1)
+
+        thr_mag = threshold(sorted_mag, -jnp.inf)
+        thr_col = threshold(sorted_col, -1)
         # Selected ⇔ ranks at or above the threshold entry in (mag desc,
         # idx asc) order — exactly per_block entries per row.
-        sel = (mag > thr_mag) | ((mag == thr_mag) & (idx <= thr_idx))
+        sel = (mag > thr_mag) | ((mag == thr_mag) & (col <= thr_col))
     else:
-        sel = valid[None, :]                                 # quota ≥ block: all
-    contrib = jnp.where(sel & valid[None, :], x, 0.0)
+        sel = valid                                          # quota ≥ block: all
+    contrib = jnp.where(sel & valid, x, 0.0)
     # Left-fold, not jnp.sum: matches the scatter-add's per-index association
     # order (thread 0 first) for bit-exact parity with the unfused path.
     acc = contrib[0]
@@ -63,17 +70,22 @@ def fused_topk_scatter_blocked(x, *, per_block: int, block_eff: int,
     """x (N, V) → (V,): sum of each row's blocked top-``per_block`` entries."""
     n, v = x.shape
     block_eff = min(block_eff, v)
-    grid = (pl.cdiv(v, block_eff),)
+    nblocks = pl.cdiv(v, block_eff)
+    rows = block_rows(block_eff)
+    xb = jnp.pad(x, ((0, 0), (0, nblocks * block_eff - v)))
+    xb = jnp.pad(xb.reshape(n, nblocks, block_eff),
+                 ((0, 0), (0, 0), (0, rows * LANES - block_eff)))
     kernel = functools.partial(_fused_scatter_kernel, per_block=per_block,
                                block_eff=block_eff, total=v)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((n, block_eff), lambda j: (0, j))],
-        out_specs=pl.BlockSpec((block_eff,), lambda j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((v,), x.dtype),
+        grid=(nblocks,),
+        in_specs=[pl.BlockSpec((n, None, rows, LANES), lambda j: (0, j, 0, 0))],
+        out_specs=pl.BlockSpec((None, rows, LANES), lambda j: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nblocks, rows, LANES), x.dtype),
         interpret=interpret,
-    )(x)
+    )(xb.reshape(n, nblocks, rows, LANES))
+    return out.reshape(nblocks, rows * LANES)[:, :block_eff].reshape(-1)[:v]
 
 
 @functools.partial(jax.jit, static_argnames=("per_block", "block_eff", "interpret"))

@@ -22,13 +22,14 @@ def _assign_kernel(pts_ref, ctr_ref, assign_ref, dist_ref):
     p2 = jnp.sum(pts * pts, axis=1, keepdims=True)            # (bn, 1)
     c2 = jnp.sum(ctr * ctr, axis=1)[None, :]                  # (1, K)
     dots = jax.lax.dot_general(pts, ctr, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     d2 = p2 - 2.0 * dots + c2                                  # (bn, K)
     assign_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)
     dist_ref[...] = jnp.min(d2, axis=1)
 
 
-def kmeans_assign_blocked(points, centers, *, block_n: int = 256,
+def kmeans_assign_blocked(points, centers, *, block_n: int = 1024,
                           interpret: bool = False):
     """points (N, D), centers (K, D) → (assign (N,) int32, dist² (N,) f32)."""
     n, d = points.shape

@@ -8,7 +8,7 @@ from repro.kernels.kmeans_assign.kernel import kmeans_assign_blocked
 
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
-def kmeans_assign(points, centers, *, block_n: int = 256, interpret=None):
+def kmeans_assign(points, centers, *, block_n: int = 1024, interpret=None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return kmeans_assign_blocked(points, centers, block_n=block_n, interpret=interpret)

@@ -1,5 +1,6 @@
 """Oracle: full distance matrix argmin."""
 
+import jax
 import jax.numpy as jnp
 
 
@@ -7,6 +8,6 @@ def kmeans_assign_ref(points, centers):
     pts = points.astype(jnp.float32)
     ctr = centers.astype(jnp.float32)
     d2 = (jnp.sum(pts**2, axis=1, keepdims=True)
-          - 2.0 * pts @ ctr.T
+          - 2.0 * jnp.matmul(pts, ctr.T, precision=jax.lax.Precision.HIGHEST)
           + jnp.sum(ctr**2, axis=1)[None, :])
     return jnp.argmin(d2, axis=1).astype(jnp.int32), jnp.min(d2, axis=1)

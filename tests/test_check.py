@@ -546,6 +546,6 @@ def test_race_demo_smoke():
                       "race_demo.py")],
         env=env, capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr
-    assert "race_demo.py:31" in proc.stdout   # read site
-    assert "race_demo.py:32" in proc.stdout   # write site
+    assert "race_demo.py:32" in proc.stdout   # read site
+    assert "race_demo.py:33" in proc.stdout   # write site
     assert "synchronized program: 0 finding(s)" in proc.stdout

@@ -41,3 +41,16 @@ def test_partition_rows_cover_disjoint(n_rows, n_threads):
         assert 0 <= lo <= hi <= n_rows
         covered.extend(range(lo, hi))
     assert covered == list(range(n_rows))
+
+
+def test_rmat_graph_shape_range_and_skew():
+    from repro.data import rmat_graph
+    v, e = 5000, 60000
+    edges = rmat_graph(v, e, seed=3)
+    assert edges.shape == (e, 2) and edges.dtype == np.int32
+    assert edges.min() >= 0 and edges.max() < v
+    assert np.array_equal(edges, rmat_graph(v, e, seed=3))
+    assert not np.array_equal(edges, rmat_graph(v, e, seed=4))
+    # R-MAT is skewed, but no vertex draws anything near zipf's share
+    share = np.bincount(edges[:, 1], minlength=v).max() / e
+    assert 1.0 / v < share < 0.05
