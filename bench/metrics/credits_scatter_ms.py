@@ -1,0 +1,9 @@
+"""Per traced round, the device time of PageRank's scatter-add: the ops
+traced under ``jax.named_scope("pagerank.scatter")``, found by the compiled
+program's scope map (``bench/program.py``)."""
+
+from bench import program
+
+
+def read(run):
+    return program.scope_ms_per_round(run, "pagerank.scatter")

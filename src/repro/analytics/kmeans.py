@@ -80,8 +80,10 @@ def fit(x, k: int, *, iters: int = 10, seed: int = 0,
     def thread_proc(ctx, pts):
         def step(_):                       # the shared centers carry the state
             with ctx.span("kmeans.round"):
-                a, _dist = assign_fn(pts, centers.get())
-                sums, counts = _partials(pts, a, k)
+                with jax.named_scope("kmeans.assign"):
+                    a, _dist = assign_fn(pts, centers.get())
+                with jax.named_scope("kmeans.partials"):
+                    sums, counts = _partials(pts, a, k)
                 flat = partials.accumulate(
                     jnp.concatenate([sums.reshape(-1), counts]), mode=mode)
                 sums_g = flat[: k * d].reshape(k, d)

@@ -12,6 +12,7 @@ vector rides along replicated (``broadcast=``).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -25,9 +26,21 @@ DAMPING = 0.85
 
 
 def _credits(src, dst, ranks, out_deg, n_vertices):
-    """Credit vector contributed by this thread's edges."""
-    w = ranks[src] / out_deg[src]
-    return jnp.zeros((n_vertices,), jnp.float32).at[dst].add(w)
+    """Credit vector contributed by this thread's edges: the gather of each
+    edge's share of its source's rank, then the scatter-add of the shares
+    into their destinations.  Each half is a ``jax.named_scope``, which
+    names its ops in a device profile."""
+    with jax.named_scope("pagerank.gather"):
+        w = ranks[src] / out_deg[src]
+    with jax.named_scope("pagerank.scatter"):
+        return jnp.zeros((n_vertices,), jnp.float32).at[dst].add(w)
+
+
+@partial(jax.jit, static_argnums=1)
+def _out_degree(edges, n_vertices: int):
+    """Every vertex's out-degree, at least 1, in f32: ``fit``'s prologue."""
+    with jax.named_scope("pagerank.out_degree"):
+        return jnp.maximum(jnp.zeros(n_vertices).at[edges[:, 0]].add(1.0), 1.0)
 
 
 def fit_reference(edges, n_vertices: int, iters: int = 10):
@@ -54,8 +67,7 @@ def fit(edges, n_vertices: int, *, iters: int = 10,
     """
     sess = session or Session(backend=backend, n_nodes=n_nodes,
                               threads_per_node=threads_per_node, mesh=mesh)
-    src_all, dst_all = jnp.asarray(edges[:, 0]), jnp.asarray(edges[:, 1])
-    out_deg = jnp.maximum(jnp.zeros(n_vertices).at[src_all].add(1.0), 1.0)
+    out_deg = _out_degree(jnp.asarray(edges), n_vertices)
     ranks = sess.def_global("ranks", jnp.full((n_vertices,), 1.0 / n_vertices))
     credits = sess.new_array("credits", (n_vertices,), sparse_k=k)
 

@@ -117,11 +117,20 @@ def accumulate(
     globally-agreed branch decision as a traced bool — the hook the SPMD
     session uses to carry a device-side "sparse branch taken" counter out of
     the program, so wire accounting can settle to the branch actually taken.
+
+    The ops are traced under ``jax.named_scope("accumulate.<mode>")`` (and
+    ``auto``'s decision under ``accumulate.auto_decide``), which names them
+    in a device profile.
     """
     mode = AccumMode(mode)
     if with_branch and mode != AccumMode.AUTO:
         raise ValueError("with_branch reports the auto rule's runtime "
                          f"decision; mode {mode.value!r} has no branch")
+    with jax.named_scope(f"accumulate.{mode.value}"):
+        return _accumulate(x, axis, mode, inner_axis, outer_axis, k, with_branch)
+
+
+def _accumulate(x, axis, mode: AccumMode, inner_axis, outer_axis, k, with_branch):
     n = x.shape[0]
 
     if mode == AccumMode.GATHER_ALL:
@@ -155,9 +164,10 @@ def accumulate(
             k = default_auto_k(n)
         # the paper's rule must agree across devices: decide on the *global*
         # benefit (all_gather of one scalar nnz flag).
-        my_ok = sparse_beneficial(x, k)
-        all_ok = jax.lax.all_gather(my_ok, axis)
-        use_sparse = jnp.all(all_ok)
+        with jax.named_scope("accumulate.auto_decide"):
+            my_ok = sparse_beneficial(x, k)
+            all_ok = jax.lax.all_gather(my_ok, axis)
+            use_sparse = jnp.all(all_ok)
         dense_fn = lambda v: accumulate(v, axis, AccumMode.REDUCE_SCATTER)
         sparse_fn = lambda v: accumulate(v, axis, AccumMode.SPARSE, k=k)
         total = jax.lax.cond(use_sparse, sparse_fn, dense_fn, x)
@@ -262,11 +272,34 @@ class DAddAccumulator:
         self._round_shape = None
 
     def _reduce_round(self) -> None:
-        """Runs under the lock when the round's last contribution arrives."""
+        """Runs under the lock when the round's last contribution arrives.
+        Armed, the reduce is one ``accumulate.round`` span carrying the
+        branch taken."""
         trc = self.tracer
-        tracing = telemetry.TRACING and trc.enabled
-        t0 = time.perf_counter() if tracing else 0.0
+        if not (telemetry.TRACING and trc.enabled):
+            self._reduce(None)
+            self._reset_round()
+            return
         wire_before = self.bytes_transferred
+        vec_len = self._round_len
+        with trc.span("accumulate-round", "accumulate.round") as span:
+            mode = self._reduce(trc)
+            wire = self.bytes_transferred - wire_before
+            span.args = {"mode": mode.value, "vec_len": vec_len, "threads": self.n,
+                         "pairs": sum(self.last_pair_counts), "wire_elements": wire}
+        if mode == AccumMode.SPARSE:
+            path = "fused" if self.fused else "sparse"
+        else:
+            path = "dense"
+        trc.count(f"accum.kernel_path.{path}")
+        trc.count("accumulate.rounds")
+        trc.count("accumulate.wire_elements", wire)
+        self._reset_round()
+
+    def _reduce(self, trc) -> AccumMode:
+        """Sum the round into the output; returns the branch taken.  ``trc``:
+        the armed tracer, else None."""
+        tracing = trc is not None
         vec_len, shape = self._round_len, self._round_shape
         if self.mode in self._DENSE_MODES:
             total = self._partial
@@ -284,8 +317,12 @@ class DAddAccumulator:
                 # compressible AND cheaper — the same globally-agreed branch
                 # as the collective.  One jitted call decides the whole round
                 # (the N contributions are same-shape by the ragged check):
-                # a single device sync instead of N small ones per round.
-                all_ok = bool(sparse_beneficial_batch(flats, k, self.block))
+                # a single device sync instead of N small ones per round,
+                # which waits for the work that made the contributions too:
+                # armed, that wait is the `accumulate.sync` span.
+                span = trc.span if tracing else telemetry.null_span
+                with span("accumulate-round", "accumulate.sync"):
+                    all_ok = bool(sparse_beneficial_batch(flats, k, self.block))
                 mode = AccumMode.SPARSE if all_ok else AccumMode.REDUCE_SCATTER
             if mode == AccumMode.SPARSE:
                 tc = time.perf_counter() if tracing else 0.0
@@ -324,23 +361,7 @@ class DAddAccumulator:
         self.last_mode = mode
         self._store_output(total)
         self.rounds += 1
-        if tracing:
-            if mode == AccumMode.SPARSE:
-                path = "fused" if self.fused else "sparse"
-            else:
-                path = "dense"
-            trc.count(f"accum.kernel_path.{path}")
-            trc.count("accumulate.rounds")
-            trc.count("accumulate.wire_elements",
-                      self.bytes_transferred - wire_before)
-            trc.add_span("accumulate-round", "accumulate.round", t0,
-                         time.perf_counter(),
-                         {"mode": mode.value, "vec_len": vec_len,
-                          "threads": self.n,
-                          "pairs": sum(self.last_pair_counts),
-                          "wire_elements":
-                              self.bytes_transferred - wire_before})
-        self._reset_round()
+        return mode
 
     def _store_output(self, total) -> None:
         """Publish the round sum, with the output's owner shard memoised.

@@ -49,8 +49,8 @@ shared-value dict is threaded through the scan carry, which is what keeps
 
 from __future__ import annotations
 
+import itertools
 import threading
-import time
 import warnings
 import weakref
 from dataclasses import dataclass, field
@@ -72,6 +72,9 @@ from repro.core.sparse import default_auto_k, pair_capacity
 from repro.core.sync import DBarrier, DSemaphore, SSPClock
 from repro.core.threads import DThreadPool, ThreadState
 from repro.data.pipeline import partition_rows
+from repro.utils.hlo import op_scopes
+
+_SESSION_IDS = itertools.count(1)
 
 
 # ---------------------------------------------------------------------------
@@ -201,11 +204,15 @@ class WorkerCtx:
     # -- tracing -------------------------------------------------------------
 
     def span(self, name: str, **args):
-        """A user-labelled span (category ``app-round``) on this thread's
-        timeline — the hook the analytics apps use to mark one algorithm
-        round.  A real span on the host backend; a no-op under SPMD, where
-        the step body is traced once and per-round host timestamps would
-        lie about device execution."""
+        """A user-labelled span — the hook the analytics apps use to mark one
+        algorithm round.  On the host backend, with tracing armed, a tracer
+        span (category ``app-round``) on this thread's timeline that also
+        lands in an active ``jax.profiler`` trace.  Under SPMD the step body
+        is traced once into a device program, where per-round host
+        timestamps would lie about device execution: there it is
+        ``jax.named_scope(name)``, which labels the device ops traced inside
+        it (the ``op_name`` of their metadata), armed or not.  ``args`` go
+        to the tracer span only.  This base context marks nothing."""
         return telemetry.NULL_SPAN
 
     # -- iteration engine ----------------------------------------------------
@@ -306,19 +313,15 @@ class SpmdWorkerCtx(WorkerCtx):
         # trace-time dense upper bound against these counts (ROADMAP item).
         self._auto_slots: List[Dict[str, Any]] = []
 
+    def span(self, name: str, **args):
+        return jax.named_scope(name)
+
     # -- iteration: one lax.scan, O(1) lowered size in `iters` ---------------
 
     def fori(self, step: Callable, carry, iters: int):
         iters = int(iters)
         if iters <= 0:
             return carry
-        trc = self._session.tracer
-        if telemetry.TRACING and trc.enabled:
-            # fori runs at *trace* time under SPMD: account the scan site and
-            # its executed trip count (nested loops multiply through
-            # _accum_repeat) — per-trip host spans would not exist anyway.
-            trc.count("spmd.scan_sites")
-            trc.count("spmd.scan_trips", iters * self._accum_repeat)
         # The shared-value dict rides in the scan carry: ref.get/set/accumulate
         # inside `step` read and write the scanned copy, so shared state
         # advances per round exactly as it does on the host backend.
@@ -698,6 +701,12 @@ class SpmdBackend:
             self.stats = stats
 
     def join(self, session: "Session", timeout: Optional[float] = None) -> List[Any]:
+        """Run the spawned program in JAX's ahead-of-time stages — trace,
+        lower, compile (where the persistent cache is looked up), run — and
+        write the shared values back.  Armed, each stage is a span of
+        category ``spmd``: ``spmd.run`` then waits for the outputs, so it
+        spans the device work, and ``spmd.compile`` carries the compiled
+        program's ``hlo_scopes`` (HLO op name -> ``op_name`` scope)."""
         if self._pending is None:
             return []
         thread_proc, data, broadcast = self._pending
@@ -705,32 +714,33 @@ class SpmdBackend:
         n = self.n_threads
         trc = session.tracer
         tracing = telemetry.TRACING and trc.enabled
-        wire_before = self.stats.bytes_transferred
-        t0 = time.perf_counter() if tracing else 0.0
-        f, data, names, auto_box = self._compile(session, thread_proc, data, broadcast)
+        span = trc.span if tracing else telemetry.null_span
+        job = {"session": session.id, "threads": n}
+        with span("spmd", "spmd.trace", **job):
+            f, data, names, auto_box = self._compile(session, thread_proc, data, broadcast)
+            args = (*data, *broadcast)
+            traced = f.trace(*args)
+        with span("spmd", "spmd.lower", **job):
+            lowered = traced.lower()
+        with span("spmd", "spmd.compile", **job) as stage:
+            compiled = lowered.compile()
+            if tracing:
+                stage.args["hlo_scopes"] = op_scopes(compiled.as_text())
+        with span("spmd", "spmd.run", **job):
+            stacked_result, stacked_shared, stacked_counts = compiled(*args)
+            if tracing:
+                jax.block_until_ready((stacked_result, stacked_shared, stacked_counts))
+        with span("spmd", "spmd.writeback", **job):
+            # settle every AUTO call site's trace-time dense bound against the
+            # branch counter the device actually accumulated (globally agreed,
+            # so replica 0's count is everyone's count)
+            for meta, counts in zip(auto_box, stacked_counts):
+                self.stats.settle_auto(meta, int(jax.device_get(counts)[0]))
+            for m in names:
+                session.store.set(m, jax.tree.map(lambda x: x[0], stacked_shared[m]))
+            out = [jax.tree.map(lambda x, i=i: x[i], stacked_result) for i in range(n)]
         if tracing:
-            # trace-time counters (scan trips, provisional traffic) landed
-            # during _compile; the span brackets trace + jit dispatch setup
-            trc.add_span("spmd", "spmd.trace", t0, time.perf_counter(),
-                         {"threads": n})
-            t1 = time.perf_counter()
-        stacked_result, stacked_shared, stacked_counts = f(*data, *broadcast)
-        # settle every AUTO call site's trace-time dense bound against the
-        # branch counter the device actually accumulated (globally agreed, so
-        # replica 0's count is everyone's count)
-        for meta, counts in zip(auto_box, stacked_counts):
-            self.stats.settle_auto(meta, int(jax.device_get(counts)[0]))
-        for m in names:
-            session.store.set(m, jax.tree.map(lambda x: x[0], stacked_shared[m]))
-        out = [jax.tree.map(lambda x, i=i: x[i], stacked_result) for i in range(n)]
-        if tracing:
-            # device code can't emit host events mid-program: like AUTO
-            # traffic, collective accounting settles once, at join
-            trc.add_span("spmd", "spmd.execute", t1, time.perf_counter(),
-                         {"threads": n})
             trc.count("spmd.joins")
-            trc.count("spmd.collective_elements",
-                      self.stats.bytes_transferred - wire_before)
         return out
 
     def wire_traffic(self) -> int:
@@ -822,6 +832,8 @@ class Session:
             else:
                 raise ValueError(f"backend must be host|spmd, got {backend!r}")
         self.backend = backend
+        #: process-unique, carried by the session's job spans (``session``)
+        self.id = next(_SESSION_IDS)
         # step.trace: trace=True arms a fresh tracer; a Tracer instance is
         # adopted as-is (FT recovery re-arms the failed session's tracer);
         # the default is a *disabled* tracer — hot paths see a false
@@ -974,9 +986,14 @@ class Session:
 
     def run(self, thread_proc: Callable, *, data: Sequence = (),
             broadcast: Sequence = (), timeout: Optional[float] = None) -> List[Any]:
-        """``spawn`` + ``join``."""
-        self.spawn(thread_proc, data=data, broadcast=broadcast)
-        return self.join(timeout)
+        """``spawn`` + ``join``.  Armed, one ``session.run`` span (category
+        ``lifecycle``) holds both and carries the session's :attr:`id`, as
+        the stages of an SPMD join do."""
+        trc = self.tracer
+        span = trc.span if telemetry.TRACING and trc.enabled else telemetry.null_span
+        with span("lifecycle", "session.run", session=self.id):
+            self.spawn(thread_proc, data=data, broadcast=broadcast)
+            return self.join(timeout)
 
     def lower(self, thread_proc: Callable, *, data: Sequence = (),
               broadcast: Sequence = ()):
@@ -992,7 +1009,7 @@ class Session:
         data = tuple(jnp.asarray(a) for a in data)
         broadcast = tuple(jnp.asarray(b) for b in broadcast)
         if telemetry.TRACING and self.tracer.enabled:
-            with self.tracer.span("spmd", "spmd.lower"):
+            with self.tracer.span("spmd", "session.lower"):
                 return self.backend.lower(self, thread_proc, data, broadcast)
         return self.backend.lower(self, thread_proc, data, broadcast)
 

@@ -14,9 +14,10 @@ through every hot path —
   wait spans, queue depth, clock skew and stall time;
 * **accumulator rounds** (`DAddAccumulator`): per-thread round spans, barrier
   wait, compress time, pair counts and the dense-vs-sparse branch taken;
-* **SPMD backend**: per-``lax.scan`` trip accounting plus trace/compile/
-  execute timing — device code cannot emit host events mid-program, so
-  collective counters settle at ``join()`` exactly like AUTO traffic does.
+* **SPMD backend**: one span per stage of a join (trace, lower, compile,
+  run, write-back) under a per-job ``session.run`` span; inside the device
+  program ``ctx.span`` and the apps' scopes are ``jax.named_scope`` scopes,
+  which label the device ops of a ``jax.profiler`` trace.
 
 Two access levels:
 
@@ -26,6 +27,12 @@ Two access levels:
   ``session.tracer.export("trace.json")`` writes a Chrome-trace /
   Perfetto-loadable JSON where a fit run renders as per-thread timelines of
   store / barrier / accumulate spans.
+* **One clock with the device**: every context-manager span
+  (:meth:`Tracer.span`) also holds a ``jax.profiler.TraceAnnotation`` of its
+  name open, so a ``jax.profiler`` trace taken around an armed session shows
+  Session's phases on its host plane, beside the device ops.  The per-op
+  spans (store ops, waits, per-thread accumulate calls) stay in the tracer
+  only: at one per op they are too many for a profile.
 * **No-op by default**: every instrumented object holds a (disabled) tracer
   and every hot path is guarded by the module-level :data:`TRACING` flag
   first — when no tracer is armed the added cost is one module-attribute
@@ -201,16 +208,20 @@ class RingSink:
 
 
 #: Span categories always materialised into the ring in record-only mode,
-#: regardless of duration: rare lifecycle edges (migration windows, SPMD
-#: trace/execute) and anomaly breadcrumbs are exactly what a post-incident
-#: dump is for, and none of them sit on a per-op hot path.
+#: regardless of duration: rare lifecycle edges (migration windows, jobs,
+#: the stages of an SPMD join) and anomaly breadcrumbs are exactly what a
+#: post-incident dump is for, and none of them sit on a per-op hot path.
 ALWAYS_RECORD = frozenset({"migration", "anomaly", "spmd", "lifecycle"})
 
 
 class _SpanCM:
-    """Context-manager span: records one complete ('X') event on exit."""
+    """Context-manager span: records one complete ('X') event on exit, and
+    holds a ``jax.profiler.TraceAnnotation`` of the same name open meanwhile,
+    so that an active ``jax.profiler`` trace carries the span on the host
+    plane, on the device ops' clock.  ``args`` may be filled in before exit
+    (the recorded event takes them as they are then)."""
 
-    __slots__ = ("_trc", "cat", "name", "args", "t0")
+    __slots__ = ("_trc", "cat", "name", "args", "t0", "_ann")
 
     def __init__(self, trc: "Tracer", cat: str, name: str, args: Optional[dict]):
         self._trc = trc
@@ -219,12 +230,18 @@ class _SpanCM:
         self.args = args
 
     def __enter__(self) -> "_SpanCM":
+        # imported on first use, so that importing this module imports no JAX;
+        # the arguments become the profile event's stats
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(self.name, **(self.args or {}))
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._trc.add_span(self.cat, self.name, self.t0, time.perf_counter(),
-                           self.args)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._trc.add_span(self.cat, self.name, self.t0, t1, self.args)
 
 
 class _NullCM:
@@ -243,22 +260,49 @@ class _NullCM:
 NULL_SPAN = _NullCM()
 
 
+def null_span(cat: str, name: str, **args) -> Any:
+    """:meth:`Tracer.span`'s signature, recording nothing: the stand-in a
+    call site picks once when its tracer is off (typed ``Any`` so that the
+    call site may use either alike)."""
+    return NULL_SPAN
+
+
 class Tracer:
     """Thread-safe structured span/counter/histogram recorder with a
     Chrome-trace (``chrome://tracing`` / Perfetto) exporter.
 
-    Span categories used by the built-in instrumentation:
+    Span categories used by the built-in instrumentation ("profile": the
+    span is a context-manager span, so it also lands in an active
+    ``jax.profiler`` trace):
 
     ========================  ====================================================
     ``store-op``              every ``ShardedStore`` get/set/inc/mget
     ``barrier-wait``          ``DBarrier.enter`` and the accumulator round barrier
     ``accumulate-round``      one span per thread per accumulator round (name
-                              ``accumulate``) + one reduce span per round (name
-                              ``accumulate.round``, carrying the branch taken)
+                              ``accumulate``); one reduce span per round (name
+                              ``accumulate.round``, carrying the branch taken;
+                              profile) and, under AUTO, its child
+                              ``accumulate.sync``: the wait for the round's
+                              device decision (profile)
     ``sync``                  semaphore acquire waits, SSP stalls
     ``app-round``             workload round boundaries via ``ctx.span(...)``
-    ``spmd``                  SPMD trace / compile+execute / lower timing
+                              on the host backend (profile); under SPMD
+                              ``ctx.span`` is a ``jax.named_scope``
+    ``spmd``                  the stages of an SPMD join, in order:
+                              ``spmd.trace``, ``spmd.lower``, ``spmd.compile``
+                              (with the persistent-cache lookup; carries
+                              ``hlo_scopes``, the compiled program's HLO op
+                              name -> ``op_name`` scope), ``spmd.run``
+                              (dispatch until the outputs are ready),
+                              ``spmd.writeback`` (AUTO settlement, store
+                              write-back); ``session.lower`` (profile, all)
+    ``lifecycle``             ``session.run``: one per ``Session.run``,
+                              carrying the session's ``id`` as ``session``
+                              (profile); recovery marks
     ========================  ====================================================
+
+    Counters of the SPMD backend: ``spmd.joins``.  Collective traffic is
+    ``Session.wire_traffic()``.
 
     Recording methods are cheap but not free: callers on hot paths must guard
     with ``telemetry.TRACING and tracer.enabled`` (every built-in call site

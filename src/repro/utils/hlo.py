@@ -1,4 +1,5 @@
-"""Parse collective traffic out of compiled HLO text.
+"""Parse collective traffic, and the named scope of every op, out of
+compiled HLO text.
 
 The roofline's collective term is not exposed by ``compiled.cost_analysis()``,
 so we parse ``compiled.as_text()`` (the post-SPMD-partitioning per-device
@@ -23,6 +24,10 @@ that is closer to what the ICI links actually carry.
 
 Shapes appearing in annotations such as ``replica_groups=[8,8]<=[64]`` cannot
 match the shape regex (no dtype prefix), so the LHS scan is safe.
+
+:func:`op_scopes` maps each instruction to the ``op_name`` of its metadata —
+the ``jax.named_scope`` path it was traced under — which is what names a
+device op of a ``jax.profiler`` trace by the program's own scopes.
 """
 
 from __future__ import annotations
@@ -163,3 +168,41 @@ def collective_bytes_from_hlo(hlo_text: str) -> CollectiveStats:
         stats.wire_bytes_by_op[op] = stats.wire_bytes_by_op.get(op, 0.0) + wire
         stats.count_by_op[op] = stats.count_by_op.get(op, 0) + 1
     return stats
+
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_OPCODE_RE = re.compile(r"(?<![\w.%\-])([a-z][a-z0-9\-]*)\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_FUSED_RE = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
+# ops that run no work of their own, and control flow, whose device event
+# spans the events of its body
+_UNTIMED_OPS = frozenset({"parameter", "constant", "get-tuple-element", "tuple",
+                          "bitcast", "while", "conditional", "call"})
+
+
+def op_scopes(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> ``op_name`` metadata, for every instruction of an
+    HLO dump that runs on the device as an op of its own and has one.  Left
+    out: the bodies of fusions (the fusion is the op), instructions that do
+    no work (parameters, constants, tuples, bitcasts), and control flow
+    (``while``, ``conditional``, ``call``), whose device events hold the
+    events of their bodies — so summing device time over the mapped names
+    counts each op's self time once."""
+    fused = set(_FUSED_RE.findall(hlo_text))
+    scopes: Dict[str, str] = {}
+    computation = None
+    for line in hlo_text.splitlines():
+        instr = _INSTR_RE.match(line)
+        if instr is None:
+            header = _COMPUTATION_RE.match(line)
+            if header is not None:
+                computation = header.group(1)
+            continue
+        op_name = _OP_NAME_RE.search(line)
+        if op_name is None or computation in fused:
+            continue
+        opcode = _OPCODE_RE.search(instr.group(2))
+        if opcode is not None and opcode.group(1) not in _UNTIMED_OPS:
+            scopes[instr.group(1)] = op_name.group(1)
+    return scopes

@@ -1,6 +1,9 @@
-"""Collective-traffic HLO parser."""
+"""Collective-traffic HLO parser, and the op -> named-scope map."""
 
-from repro.utils.hlo import collective_bytes_from_hlo
+import jax
+import jax.numpy as jnp
+
+from repro.utils.hlo import collective_bytes_from_hlo, op_scopes
 
 
 HLO = """
@@ -38,3 +41,40 @@ def test_replica_group_list_form():
     s = collective_bytes_from_hlo(
         "%x = f32[8]{0} all-gather(%p), replica_groups={{0,1,2,3}}, dimensions={0}")
     assert s.bytes_by_op["all-gather"] == 8 * 4 / 4
+
+
+def test_op_scopes_maps_ops_that_run():
+    def f(src, r):
+        def body(i, r):
+            with jax.named_scope("demo.gather"):
+                w = r[src] * 2.0
+            with jax.named_scope("demo.scatter"):
+                return jnp.zeros_like(r).at[src].add(w)
+        return jax.lax.fori_loop(0, 3, body, r)
+
+    text = jax.jit(f).lower(jnp.arange(32) % 8, jnp.ones(8)).compile().as_text()
+    scopes = op_scopes(text)
+    paths = [s.split("/") for s in scopes.values()]
+    assert any("demo.gather" in p for p in paths) and any("demo.scatter" in p for p in paths)
+    # control flow and ops that do no work are left out
+    for line in text.splitlines():
+        if any(f" {op}(" in line for op in ("while", "parameter", "constant")):
+            assert line.split("=")[0].split()[-1].lstrip("%") not in scopes, line
+
+
+def test_op_scopes_by_hand():
+    text = """HloModule demo
+
+%fused_computation (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  ROOT %mul.1 = f32[4]{0} multiply(%p, %p), metadata={op_name="jit(f)/a/mul"}
+}
+
+ENTRY %main (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0), metadata={op_name="x"}
+  %fusion = f32[4]{0:T(1024)} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/a/mul"}
+  %while.2 = (s32[], f32[4]{0}) while(%t), condition=%c, body=%b, metadata={op_name="jit(f)/while"}
+  ROOT %sort.3 = f32[4]{0} sort(%fusion), dimensions={0}, to_apply=%cmp, metadata={op_name="jit(f)/b/sort"}
+}
+"""
+    assert op_scopes(text) == {"fusion": "jit(f)/a/mul", "sort.3": "jit(f)/b/sort"}

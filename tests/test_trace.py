@@ -205,9 +205,9 @@ def test_store_op_shard_attribution_and_lock_wait():
 
 def test_metrics_collective_bytes_parity_host_spmd():
     """The same 1-thread workload reports identical wire_traffic through
-    metrics() on both backends, and each backend's tracer counter agrees
-    with its own figure (host: accumulate.wire_elements; SPMD:
-    spmd.collective_elements settled at join)."""
+    metrics() on both backends; the host tracer's accumulate.wire_elements
+    agrees with it, and the SPMD join records its five stages, in order and
+    each inside the job's session.run span, all carrying the session's id."""
     V, R = 128, 3
     rows = jnp.ones((2, V))
 
@@ -224,17 +224,24 @@ def test_metrics_collective_bytes_parity_host_spmd():
 
             res = sess.run(proc, data=(rows,))
             m = sess.metrics()
-            return np.asarray(res[0]), m, sess.tracer.counters()
+            return np.asarray(res[0]), m, sess.tracer.counters(), sess
         finally:
             sess.tracer.disable()
 
-    r_h, m_h, c_h = run("host")
-    r_s, m_s, c_s = run("spmd")
+    r_h, m_h, c_h, _ = run("host")
+    r_s, m_s, c_s, sess = run("spmd")
     np.testing.assert_allclose(r_h, r_s, rtol=1e-6)
     assert m_h["wire_traffic"] == m_s["wire_traffic"] == 2 * V * R
     assert c_h["accumulate.wire_elements"] == m_h["wire_traffic"]
-    assert c_s["spmd.collective_elements"] == m_s["wire_traffic"]
-    assert c_s["spmd.scan_trips"] == R and c_s["spmd.scan_sites"] == 1
+    assert c_s["spmd.joins"] == 1
+    (job,) = sess.tracer.spans(name="session.run")
+    stages = sess.tracer.spans(cat="spmd")
+    assert [s["name"] for s in stages] == ["spmd.trace", "spmd.lower", "spmd.compile",
+                                           "spmd.run", "spmd.writeback"]
+    ends = [job["ts"]] + [s["ts"] + s["dur"] for s in stages]
+    for s, prev_end in zip(stages, ends):
+        assert prev_end <= s["ts"] and s["ts"] + s["dur"] <= job["ts"] + job["dur"]
+    assert {s["args"]["session"] for s in stages + [job]} == {sess.id}
 
 
 # -- stats unification: pinned key sets, deprecated views intact --------------
