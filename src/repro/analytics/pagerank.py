@@ -29,9 +29,14 @@ def _credits(src, dst, ranks, out_deg, n_vertices):
     """Credit vector contributed by this thread's edges: the gather of each
     edge's share of its source's rank, then the scatter-add of the shares
     into their destinations.  Each half is a ``jax.named_scope``, which
-    names its ops in a device profile."""
+    names its ops in a device profile.
+
+    The share is divided per vertex, then gathered once per edge:
+    ``(ranks / out_deg)[src]`` is ``ranks[src] / out_deg[src]`` bit for bit
+    (the same operands through the same f32 divide) at V divides and one
+    E-length gather."""
     with jax.named_scope("pagerank.gather"):
-        w = ranks[src] / out_deg[src]
+        w = (ranks / out_deg)[src]
     with jax.named_scope("pagerank.scatter"):
         return jnp.zeros((n_vertices,), jnp.float32).at[dst].add(w)
 

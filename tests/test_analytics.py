@@ -1,11 +1,17 @@
 """The paper's four applications: threads == reference, traffic accounting."""
 
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.analytics import kmeans, logreg, nmf, pagerank
-from repro.core import AccumMode
-from repro.data import kmeans_dataset, logreg_dataset, nmf_dataset, powerlaw_graph
+from repro.core import AccumMode, Session
+from repro.data import (kmeans_dataset, logreg_dataset, nmf_dataset, powerlaw_graph,
+                        rmat_graph)
+from repro.utils.hlo import op_scopes
 
 
 def test_logreg_threads_match_reference():
@@ -61,6 +67,88 @@ def test_pagerank_threads_match_reference():
                                        iters=10, mode=AccumMode.AUTO)
     np.testing.assert_allclose(rt, rr, rtol=1e-4, atol=1e-6)
     assert abs(float(np.sum(rr)) - 1.0) < 0.05  # ranks ≈ distribution
+
+
+def _repeats_and_self_loops(n_vertices):
+    """Every vertex loops to itself twice and sends one edge three times."""
+    v = np.arange(n_vertices, dtype=np.int32)
+    loops = np.stack([v, v], axis=1)
+    out = np.stack([v, (v * 7 + 3) % n_vertices], axis=1)
+    return np.concatenate([loops, out, loops, out, out])
+
+
+def _sinks(n_vertices):
+    """Only the even vertices send edges: the odd half has no out-edges."""
+    rng = np.random.default_rng(11)
+    src = 2 * rng.integers(0, n_vertices // 2, size=6 * n_vertices)
+    dst = rng.integers(0, n_vertices, size=src.size)
+    return np.stack([src, dst], axis=1).astype(np.int32)
+
+
+GRAPHS = {
+    "powerlaw": lambda n: powerlaw_graph(n, 8, seed=5),
+    "rmat": lambda n: rmat_graph(n, 16 * n, seed=6),
+    "self_loops_and_repeats": _repeats_and_self_loops,
+    "sinks": _sinks,
+}
+
+
+def _credit_inputs(graph, n_vertices=512):
+    edges = GRAPHS[graph](n_vertices)
+    src, dst = jnp.asarray(edges[:, 0]), jnp.asarray(edges[:, 1])
+    out_deg = jnp.maximum(jnp.zeros(n_vertices).at[src].add(1.0), 1.0)
+    ranks = jnp.asarray(np.random.default_rng(7).random(n_vertices), jnp.float32)
+    return src, dst, ranks / ranks.sum(), out_deg, n_vertices
+
+
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+def test_credits_equal_the_per_edge_division_bit_for_bit(graph):
+    """Dividing per vertex and gathering once gives each edge the same f32
+    quotient as gathering both operands and dividing per edge."""
+    src, dst, ranks, out_deg, n = _credit_inputs(graph)
+    if graph == "sinks":
+        assert int(jnp.sum(jnp.zeros(n).at[src].add(1.0) == 0)) >= n // 2
+    two_gathers = jax.jit(lambda s, d, r, o: jnp.zeros((n,), jnp.float32).at[d].add(
+        r[s] / o[s]))(src, dst, ranks, out_deg)
+    jitted = jax.jit(pagerank._credits, static_argnums=4)(src, dst, ranks, out_deg, n)
+    eager = pagerank._credits(src, dst, ranks, out_deg, n)     # the host backend's form
+    assert jitted.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(jitted), np.asarray(two_gathers))
+    np.testing.assert_array_equal(np.asarray(eager), np.asarray(two_gathers))
+
+
+def test_credits_hold_one_gather():
+    src, dst, ranks, out_deg, n = _credit_inputs("powerlaw")
+    text = jax.jit(pagerank._credits, static_argnums=4).lower(
+        src, dst, ranks, out_deg, n).compile().as_text()
+    assert len(re.findall(r"\bgather\(", text)) == 1
+
+
+def test_spmd_pagerank_program_gathers_once_per_edge(monkeypatch):
+    """The SPMD program ``fit`` runs, lowered through ``Session.lower``: one
+    gather under ``pagerank.gather``, and one device op there with an f32
+    output of one value per edge."""
+    n_vertices, edges = 256, powerlaw_graph(256, 16, seed=2)
+    sess = Session(backend="spmd")
+    programs = []
+
+    def lower_instead(thread_proc, *, data=(), broadcast=(), timeout=None):
+        lowered = sess.lower(thread_proc, data=data, broadcast=broadcast)
+        programs.append(lowered.compile().as_text())
+        return []
+    monkeypatch.setattr(sess, "run", lower_instead)
+    pagerank.fit(edges, n_vertices, iters=3, session=sess)
+    (text,) = programs
+    gathers = [line for line in text.splitlines()
+               if re.search(r"\bgather\(", line) and "pagerank.gather" in line]
+    assert len(gathers) == 1, gathers
+    outputs = {m.group(1): (m.group(2), m.group(3)) for m in
+               re.finditer(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([0-9,]*)\]", text, re.M)}
+    per_edge = [op for op, scope in op_scopes(text).items()
+                if "pagerank.gather" in scope.split("/") and op in outputs
+                and outputs[op][0] == "f32"
+                and np.prod([int(d) for d in outputs[op][1].split(",")]) == len(edges)]
+    assert len(per_edge) == 1, per_edge
 
 
 def test_deprecated_shims_warn_and_stay_correct():

@@ -11,6 +11,8 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -84,3 +86,29 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pagerank_credits_divide_per_vertex_on_v5e(one_chip):
+    """``_credits`` for a described v5e on a Graph 500 SCALE 20 graph
+    (edgefactor 16; SCALE 22's scatter sort takes ~20 s to compile): the
+    divide is a V-length op of its own, and the one E-length gather reads
+    its result and divides nothing per edge."""
+    from repro.analytics import pagerank
+    from repro.utils.hlo import op_scopes
+
+    n_vertices, n_edges = 1 << 20, 1 << 24
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            [((n_edges,), jnp.int32)] * 2 + [((n_vertices,), jnp.float32)] * 2]
+    text = jax.jit(pagerank._credits, static_argnums=4).lower(
+        *args, n_vertices).compile().as_text()
+    ops = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r"^\s*(?:ROOT )?%(\S+) = (f32\[\d+\])\S* (\w+)\(", text, re.M)}
+    scoped = {op: ops[op] for op, scope in op_scopes(text).items()
+              if op in ops and "pagerank.gather" in scope.split("/")}
+    assert sorted(scoped.values()) == [(f"f32[{n_vertices}]", "divide"),
+                                       (f"f32[{n_edges}]", "fusion")], scoped
+    (gather,) = [op for op, (shape, _) in scoped.items() if shape == f"f32[{n_edges}]"]
+    body = re.search(rf"%{re.escape(gather)} = .*?calls=%([\w.\-]+)", text).group(1)
+    start = text.index(f"%{body} ")
+    fused = text[start:text.index("\n}", start)]
+    assert " gather(" in fused and " divide(" not in fused
