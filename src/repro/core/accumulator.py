@@ -312,6 +312,7 @@ class DAddAccumulator:
             # along flattened, mirroring the SPMD ctx's rank normalisation)
             flats = [v.reshape(-1) for v in self._vecs]
             mode = self.mode
+            span = trc.span if tracing else telemetry.null_span
             if mode == AccumMode.AUTO:
                 # pairs only when every contribution is losslessly
                 # compressible AND cheaper — the same globally-agreed branch
@@ -320,7 +321,6 @@ class DAddAccumulator:
                 # a single device sync instead of N small ones per round,
                 # which waits for the work that made the contributions too:
                 # armed, that wait is the `accumulate.sync` span.
-                span = trc.span if tracing else telemetry.null_span
                 with span("accumulate-round", "accumulate.sync"):
                     all_ok = bool(sparse_beneficial_batch(flats, k, self.block))
                 mode = AccumMode.SPARSE if all_ok else AccumMode.REDUCE_SCATTER
@@ -331,9 +331,13 @@ class DAddAccumulator:
                     # round — no pair arrays or dense intermediates; the
                     # logical pair count is the static capacity either way
                     # (under jit num_pairs always equals pair_capacity), so
-                    # wire accounting is unchanged
-                    total = blocked_topk_accumulate(
-                        jnp.stack(flats), k, self.block).reshape(shape)
+                    # wire accounting is unchanged.  Armed, the launch is the
+                    # `accumulate.fused` span, which waits for the sum.
+                    with span("accumulate-round", "accumulate.fused"):
+                        total = blocked_topk_accumulate(
+                            jnp.stack(flats), k, self.block).reshape(shape)
+                        if tracing:
+                            total.block_until_ready()
                     self.last_pair_counts = (
                         [pair_capacity(vec_len, k, self.block)] * self.n)
                 else:

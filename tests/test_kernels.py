@@ -136,6 +136,29 @@ def test_fused_scatter_bitexact_vs_unfused(n, v, k, block):
         assert np.array_equal(np.asarray(ref), np.asarray(fused_jnp)), density
 
 
+def test_fused_scatter_ragged_last_block_matches_jnp_bit_for_bit():
+    """A round whose length is no multiple of the block (V = 4·1024 + 576,
+    the last block ragged as at V = 10**6): the Pallas kernel in interpret
+    mode equals the jnp reference bit for bit, sparse rows and rows that
+    overflow a block's quota alike."""
+    from repro.core.sparse import _fused_accumulate_jnp, block_layout
+    from repro.kernels.accumulate.fused_scatter import fused_topk_scatter
+    v = 4 * 1024 + 576
+    nblocks, block_eff, per_block = block_layout(v, v // 4)
+    assert (nblocks, per_block) == (5, 234)
+    rng = np.random.default_rng(16)
+    for density in (0.05, 0.5):
+        mat = rng.normal(size=(4, v)).astype(np.float32)
+        mat[rng.random((4, v)) >= density] = 0.0
+        mat[:, -7:] = 3.0                       # the ragged block's last lanes
+        mat = jnp.asarray(mat)
+        got = fused_topk_scatter(mat, per_block=per_block, block_eff=block_eff,
+                                 interpret=True)
+        want = _fused_accumulate_jnp(mat, nblocks, block_eff, per_block)
+        assert np.array_equal(np.asarray(got), np.asarray(want)), density
+        assert float(got[-1]) == 12.0
+
+
 def test_fused_scatter_kernel_validation():
     from repro.kernels.accumulate.fused_scatter import fused_topk_scatter
     with pytest.raises(ValueError, match=r"\(N, V\)"):

@@ -4,7 +4,8 @@ Nothing runs: the installed TPU compiler compiles each kernel for a chip that
 is described, not attached, and the program must hold the Mosaic kernel
 (``tpu_custom_call``).  Widths are those of the chip smoke test: PageRank's
 credit vector at SNAP soc-LiveJournal1's vertex count, a host-backend round
-of 4 threads, and k-means at UCI Covertype's shape.
+of 4 threads, and k-means at UCI Covertype's shape; and a host-backend
+round of sparse logistic regression over 10**6 hashed features.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -55,13 +56,13 @@ def _topk(method, k_per_block):
     return run, [((V,), jnp.float32)]
 
 
-def _fused():
+def _fused(v=V):
     from repro.kernels.accumulate.fused_scatter import fused_topk_scatter
 
     def run(x):
         return fused_topk_scatter(x, per_block=256, block_eff=1024,
                                   interpret=False)
-    return run, [((4, V), jnp.float32)]
+    return run, [((4, v), jnp.float32)]
 
 
 def _kmeans():
@@ -76,6 +77,9 @@ CASES = {
     "topk_argmax_k16": lambda: _topk("argmax", 16),
     "topk_bitonic_k256": lambda: _topk("bitonic", 256),
     "fused_topk_scatter_4xV": _fused,
+    # a sparse logistic regression round at 10**6 hashed features: 977
+    # blocks, 576 valid lanes in the last
+    "fused_topk_scatter_4x1M": lambda: _fused(1_000_000),
     "kmeans_assign_covtype": _kmeans,
 }
 

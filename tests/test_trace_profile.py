@@ -14,7 +14,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.analytics import kmeans, pagerank
+from repro.analytics import kmeans, logreg, pagerank
 from repro.core import Session
 
 STAGES = ("spmd.trace", "spmd.lower", "spmd.compile", "spmd.run", "spmd.writeback")
@@ -144,3 +144,69 @@ def test_flight_recorder_keeps_the_job_and_its_stages():
 def test_session_ids_are_unique(backend):
     a, b = Session(backend=backend), Session(backend=backend)
     assert a.id != b.id
+
+
+def _ell(n_rows, n_features, width=6, seed=0):
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, n_features, size=(n_rows, width)).astype(np.int32)
+    return (idx, np.ones((n_rows, width), np.float32)), (rng.random(n_rows) < 0.3).astype(
+        np.float32)
+
+
+def test_fused_span_sits_in_every_pairs_round_and_no_dense_one():
+    """Sparse rounds (8 rows a thread over 4,096 features) take the pairs
+    path, each with its ``accumulate.fused`` span inside the round's;
+    overflowing rounds (128 rows a thread over 2,048) go dense, with none."""
+    rounds, fused = [], []
+    for n_features, batch, iters, width in ((4096, 8, 3, 6), (2048, 128, 2, 8)):
+        sess = Session(backend="host", n_nodes=2, threads_per_node=2, trace=True)
+        try:
+            rows, y = _ell(4 * batch * iters, n_features, width)
+            logreg.fit(rows, y, n_features=n_features, batch=batch, iters=iters,
+                       mode="auto", session=sess)
+        finally:
+            sess.tracer.disable()
+        rounds += sess.tracer.spans(name="accumulate.round")
+        fused += sess.tracer.spans(name="accumulate.fused")
+    assert [r["args"]["mode"] for r in rounds] == ["sparse"] * 3 + ["reduce_scatter"] * 2
+    assert len(fused) == 3
+    for r, f in zip(rounds, fused):
+        assert r["ts"] <= f["ts"] and f["ts"] + f["dur"] <= r["ts"] + r["dur"]
+
+
+def test_disarmed_pairs_round_makes_no_annotation(monkeypatch):
+    made = []
+
+    class Counting:
+        def __init__(self, name, **kwargs):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    rows, y = _ell(4 * 8 * 2, 4096)
+    _, sess = logreg.fit(rows, y, n_features=4096, batch=8, iters=2, mode="auto")
+    assert sess.tracer.counters() == {} and made == []
+    armed = Session(backend="host", n_nodes=2, threads_per_node=2, trace=True)
+    try:
+        logreg.fit(rows, y, n_features=4096, batch=8, iters=2, mode="auto", session=armed)
+    finally:
+        armed.tracer.disable()
+    assert made.count("accumulate.fused") == 2
+
+
+def test_logreg_scopes():
+    rows, y = _ell(64, 512)
+    sess = Session(backend="spmd", trace=True)
+    try:
+        logreg.fit(rows, y, n_features=512, batch=8, iters=2, session=sess)
+    finally:
+        sess.tracer.disable()
+    scopes = [s.split("/") for s in
+              sess.tracer.spans(name="spmd.compile")[0]["args"]["hlo_scopes"].values()]
+    for scope in ("logreg.round", "logreg.margin", "logreg.grad"):
+        assert any(scope in s for s in scopes), scope
