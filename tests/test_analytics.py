@@ -93,8 +93,46 @@ GRAPHS = {
 }
 
 
+def _few_targets(n_vertices):
+    """Every edge goes into one of eight vertices: most have no in-edges."""
+    rng = np.random.default_rng(12)
+    src = rng.integers(0, n_vertices, size=3 * n_vertices)
+    dst = rng.choice(n_vertices, size=8, replace=False)[rng.integers(0, 8, size=src.size)]
+    return np.stack([src, dst], axis=1).astype(np.int32)
+
+
+def _one_target(n_vertices):
+    """Every edge goes into one vertex: its run crosses every row."""
+    src = np.random.default_rng(13).integers(0, n_vertices, size=5 * n_vertices)
+    return np.stack([src, np.full_like(src, 7)], axis=1).astype(np.int32)
+
+
+def _random_edges(n_edges, seed):
+    def make(n_vertices):
+        rng = np.random.default_rng(seed)
+        return rng.integers(0, n_vertices, size=(n_edges, 2)).astype(np.int32)
+    return make
+
+
+def _first_and_last(n_vertices):
+    """Edges only into vertex 0 and vertex V-1, from vertex 0 and V-1."""
+    ends = np.array([0, n_vertices - 1], np.int32)
+    return np.stack(np.meshgrid(ends, ends), axis=-1).reshape(-1, 2).repeat(3, axis=0)
+
+
+# credit sums at the edges of the destination order's rows and runs
+EDGE_CASES = {
+    "few_targets": _few_targets,
+    "one_target": _one_target,
+    "ragged_last_row": _random_edges(3 * pagerank.LANES + 5, 14),
+    "less_than_a_row": _random_edges(37, 15),
+    "first_and_last_vertex": _first_and_last,
+    "single_edge": lambda n: np.array([[3, n - 2]], np.int32),
+}
+
+
 def _credit_inputs(graph, n_vertices=512):
-    edges = GRAPHS[graph](n_vertices)
+    edges = {**GRAPHS, **EDGE_CASES}[graph](n_vertices)
     src, dst = jnp.asarray(edges[:, 0]), jnp.asarray(edges[:, 1])
     out_deg = jnp.maximum(jnp.zeros(n_vertices).at[src].add(1.0), 1.0)
     ranks = jnp.asarray(np.random.default_rng(7).random(n_vertices), jnp.float32)
@@ -103,32 +141,71 @@ def _credit_inputs(graph, n_vertices=512):
 
 @pytest.mark.parametrize("graph", sorted(GRAPHS))
 def test_credits_equal_the_per_edge_division_bit_for_bit(graph):
-    """Dividing per vertex and gathering once gives each edge the same f32
-    quotient as gathering both operands and dividing per edge."""
+    """The gather half of the credits: dividing per vertex and gathering once
+    gives each edge, in destination order, the same f32 quotient as gathering
+    both operands and dividing per edge."""
     src, dst, ranks, out_deg, n = _credit_inputs(graph)
     if graph == "sinks":
         assert int(jnp.sum(jnp.zeros(n).at[src].add(1.0) == 0)) >= n // 2
-    two_gathers = jax.jit(lambda s, d, r, o: jnp.zeros((n,), jnp.float32).at[d].add(
-        r[s] / o[s]))(src, dst, ranks, out_deg)
-    jitted = jax.jit(pagerank._credits, static_argnums=4)(src, dst, ranks, out_deg, n)
-    eager = pagerank._credits(src, dst, ranks, out_deg, n)     # the host backend's form
+    by_src, _, _ = pagerank._by_dst(src, dst, n)
+    two_gathers = jax.jit(lambda s, r, o: r[s] / o[s])(by_src, ranks, out_deg)
+    jitted = jax.jit(pagerank._shares)(by_src, ranks, out_deg)
+    eager = pagerank._shares(by_src, ranks, out_deg)     # the host backend's form
     assert jitted.dtype == jnp.float32
     np.testing.assert_array_equal(np.asarray(jitted), np.asarray(two_gathers))
     np.testing.assert_array_equal(np.asarray(eager), np.asarray(two_gathers))
 
 
+@pytest.mark.parametrize("graph", sorted(GRAPHS) + sorted(EDGE_CASES))
+def test_credits_sum_each_vertex_in_edges(graph):
+    """Each credit is the sum of its own in-edges' shares, and exactly 0 with
+    none.  The sum is a tree: at most log2(LANES) levels within a row,
+    ceil(log2(rows)) across rows and one to join them; a tree of depth d
+    rounds to within d·eps/2 of the exact sum of its non-negative terms
+    (at most 7 ulps of the largest credit at these sizes)."""
+    src, dst, ranks, out_deg, n = _credit_inputs(graph)
+    by_src, reach, ends = pagerank._by_dst(src, dst, n)
+    got = np.asarray(pagerank._credits(by_src, reach, ranks, out_deg, ends))
+    shares = np.asarray(ranks / out_deg, np.float64)[np.asarray(src)]
+    want = np.bincount(np.asarray(dst), weights=shares, minlength=n)
+    rows = reach.shape[0]
+    depth = np.log2(pagerank.LANES) + np.ceil(np.log2(rows)) + 1
+    eps = np.finfo(np.float32).eps
+    assert got.dtype == np.float32 and got.shape == (n,)
+    np.testing.assert_array_equal(got[want == 0], 0.0)
+    assert np.max(np.abs(got - want)) <= depth * eps / 2 * np.max(want)
+
+
 def test_credits_hold_one_gather():
+    """The compiled round reads each edge once (one gather with an output of
+    one value per edge, padded to whole rows), reads each vertex's credit at
+    its run's end (gathers of one value per vertex), and sorts and scatters
+    nothing."""
     src, dst, ranks, out_deg, n = _credit_inputs("powerlaw")
-    text = jax.jit(pagerank._credits, static_argnums=4).lower(
-        src, dst, ranks, out_deg, n).compile().as_text()
-    assert len(re.findall(r"\bgather\(", text)) == 1
+    by_src, reach, ends = pagerank._by_dst(src, dst, n)
+    text = pagerank._credits.lower(by_src, reach, ranks, out_deg, ends).compile().as_text()
+    sizes = [int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+             for m in re.finditer(r"= \w+\[([0-9,]*)\]\S* gather\(", text)]
+    assert sorted(sizes)[-1] == by_src.size >= len(src)
+    assert sorted(sizes)[:-1] and set(sorted(sizes)[:-1]) == {n}, sizes
+    assert not re.search(r"\b(sort|scatter)\(", text)
 
 
-def test_spmd_pagerank_program_gathers_once_per_edge(monkeypatch):
-    """The SPMD program ``fit`` runs, lowered through ``Session.lower``: one
-    gather under ``pagerank.gather``, and one device op there with an f32
-    output of one value per edge."""
-    n_vertices, edges = 256, powerlaw_graph(256, 16, seed=2)
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+@pytest.mark.parametrize("backend", ["spmd", "host"])
+def test_pagerank_fit_matches_reference(backend, graph):
+    """``fit`` on the SPMD backend and on the host backend's 2x2 threads
+    agrees with ``fit_reference``, which scatter-adds edge by edge."""
+    n = 512
+    edges = GRAPHS[graph](n)
+    ref = pagerank.fit_reference(edges, n, iters=6)
+    ranks, _ = pagerank.fit(edges, n, iters=6, backend=backend, n_nodes=2,
+                            threads_per_node=2)
+    np.testing.assert_allclose(ranks, ref, rtol=1e-5, atol=1e-7)
+
+
+def _spmd_program(n_vertices, edges, iters=3):
+    """The compiled SPMD program ``fit`` runs, lowered through ``Session.lower``."""
     sess = Session(backend="spmd")
     programs = []
 
@@ -136,19 +213,48 @@ def test_spmd_pagerank_program_gathers_once_per_edge(monkeypatch):
         lowered = sess.lower(thread_proc, data=data, broadcast=broadcast)
         programs.append(lowered.compile().as_text())
         return []
-    monkeypatch.setattr(sess, "run", lower_instead)
-    pagerank.fit(edges, n_vertices, iters=3, session=sess)
+    sess.run = lower_instead
+    pagerank.fit(edges, n_vertices, iters=iters, session=sess)
     (text,) = programs
+    return text
+
+
+def test_spmd_pagerank_program_gathers_once_per_edge():
+    """The SPMD program ``fit`` runs: one gather under ``pagerank.gather``,
+    and one device op there with an f32 output of one value per edge (the
+    edges padded to whole rows of the destination order)."""
+    n_vertices, edges = 256, powerlaw_graph(256, 16, seed=2)
+    text = _spmd_program(n_vertices, edges)
     gathers = [line for line in text.splitlines()
                if re.search(r"\bgather\(", line) and "pagerank.gather" in line]
     assert len(gathers) == 1, gathers
     outputs = {m.group(1): (m.group(2), m.group(3)) for m in
                re.finditer(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([0-9,]*)\]", text, re.M)}
+    per_edge = -(-len(edges) // pagerank.LANES) * pagerank.LANES
     per_edge = [op for op, scope in op_scopes(text).items()
                 if "pagerank.gather" in scope.split("/") and op in outputs
                 and outputs[op][0] == "f32"
-                and np.prod([int(d) for d in outputs[op][1].split(",")]) == len(edges)]
+                and np.prod([int(d) for d in outputs[op][1].split(",")]) == per_edge]
     assert len(per_edge) == 1, per_edge
+
+
+def test_spmd_pagerank_sorts_once_per_job_and_never_in_a_round():
+    """In the SPMD program ``fit`` runs, the one sort is the destination
+    order's, outside the rounds' loop, and PageRank's own part of a round
+    holds no sort and no scatter (the accumulator's sparse branch scatters
+    into the pairs it receives, and is not PageRank's)."""
+    text = _spmd_program(256, powerlaw_graph(256, 16, seed=2))
+    scope = {}
+    for line in text.splitlines():
+        if re.search(r"\b(sort|scatter)\(", line):
+            scope[line] = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+    sorts = [s for line, s in scope.items() if re.search(r"\bsort\(", line)]
+    assert len(sorts) == 1 and "pagerank.by_dst" in sorts[0], sorts
+    assert "while" not in sorts[0] and "pagerank.round" not in sorts[0]
+    in_round = [s for s in scope.values() if "pagerank.round" in s]
+    assert all(any(p.startswith("accumulate.") for p in s) for s in in_round), in_round
+    assert not [s for s in scope.values()
+                if {"pagerank.gather", "pagerank.scatter"} & set(s)]
 
 
 def test_deprecated_shims_warn_and_stay_correct():

@@ -92,19 +92,31 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_pagerank_credits_divide_per_vertex_on_v5e(one_chip):
-    """``_credits`` for a described v5e on a Graph 500 SCALE 20 graph
-    (edgefactor 16; SCALE 22's scatter sort takes ~20 s to compile): the
-    divide is a V-length op of its own, and the one E-length gather reads
-    its result and divides nothing per edge."""
+@pytest.fixture(scope="module")
+def pagerank_round(one_chip):
+    """``_credits`` compiled for a described v5e on a Graph 500 SCALE 20 graph
+    (edgefactor 16), over the destination order ``_by_dst`` builds: the
+    compiled text, V and the padded edge count."""
     from repro.analytics import pagerank
-    from repro.utils.hlo import op_scopes
 
     n_vertices, n_edges = 1 << 20, 1 << 24
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
-            [((n_edges,), jnp.int32)] * 2 + [((n_vertices,), jnp.float32)] * 2]
-    text = jax.jit(pagerank._credits, static_argnums=4).lower(
-        *args, n_vertices).compile().as_text()
+    edge = jax.ShapeDtypeStruct((n_edges,), jnp.int32)
+    order = jax.eval_shape(lambda s, d: pagerank._by_dst(s, d, n_vertices), edge, edge)
+    src, reach, ends = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), order)
+    vec = jax.ShapeDtypeStruct((n_vertices,), jnp.float32, sharding=one_chip)
+    text = pagerank._credits.lower(src, reach, vec, vec, ends).compile().as_text()
+    return text, n_vertices, src.size
+
+
+def test_pagerank_credits_divide_per_vertex_on_v5e(pagerank_round):
+    """The round's gather half for a described v5e at SCALE 20 (SCALE 22's
+    destination sort takes minutes to compile here): the divide is a
+    V-length op of its own, and the one E-length gather reads its result and
+    divides nothing per edge."""
+    from repro.utils.hlo import op_scopes
+
+    text, n_vertices, n_edges = pagerank_round
     ops = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
         r"^\s*(?:ROOT )?%(\S+) = (f32\[\d+\])\S* (\w+)\(", text, re.M)}
     scoped = {op: ops[op] for op, scope in op_scopes(text).items()
@@ -116,3 +128,26 @@ def test_pagerank_credits_divide_per_vertex_on_v5e(one_chip):
     start = text.index(f"%{body} ")
     fused = text[start:text.index("\n}", start)]
     assert " gather(" in fused and " divide(" not in fused
+
+
+def test_pagerank_round_sorts_and_scatters_nothing_on_v5e(pagerank_round):
+    """The round compiled for a described v5e at SCALE 20 sums each vertex's
+    run of in-edges without a sort or a scatter: the destination order is
+    built once per job, outside the round."""
+    text, _, _ = pagerank_round
+    assert " gather(" in text
+    assert not re.search(r"\b(sort|scatter)\(", text)
+
+
+def test_pagerank_destination_order_sorts_once_on_v5e(one_chip):
+    """``_by_dst`` for a described v5e on the Graph 500 SCALE 22 graph of the
+    benchmark: one sort, the destination order's own.  The per-row scatter
+    that finds the markers' rows is told its indices are sorted; untold, XLA
+    sorts its 557,056 updates first at this size (not at SCALE 20)."""
+    from repro.analytics import pagerank
+
+    n_vertices, n_edges = 1 << 22, 1 << 26
+    edge = jax.ShapeDtypeStruct((n_edges,), jnp.int32, sharding=one_chip)
+    text = pagerank._by_dst.lower(edge, edge, n_vertices).compile().as_text()
+    sorts = [line for line in text.splitlines() if re.search(r"\bsort\(", line)]
+    assert len(sorts) == 1 and "pagerank.by_dst" in sorts[0], sorts
