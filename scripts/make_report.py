@@ -56,52 +56,6 @@ def improvement_note(r):
     return "compute-bound: raise MXU utilisation (bigger per-device tiles, skip causal-masked blocks, fewer remat recomputes)"
 
 
-def trace_section(bench_path):
-    """§Observability: the step.trace overhead table from BENCH_trace.json."""
-    r = json.load(open(bench_path))
-    print("\n### step.trace overhead (benchmarks/BENCH_trace.json)\n")
-    print("| workload | tracer | seconds | ops/s | events |")
-    print("|---|---|---|---|---|")
-    for wl, key in (("rw mix (S=8, 8 threads)", "rw"), ("logreg fit", "logreg")):
-        for state in ("noop", "disabled", "enabled"):
-            row = r.get(f"{key}_{state}")
-            if row is None:
-                continue
-            ops = f"{row['ops_per_sec']:.0f}" if "ops_per_sec" in row else "—"
-            print(f"| {wl} | {state} | {row['seconds']:.4f} | {ops} | "
-                  f"{row['events']} |")
-    pct = r.get("disabled_overhead_pct_rw")
-    if pct is not None:
-        ok = "within" if r.get("disabled_within_limit") else "OVER"
-        print(f"\nDisabled-tracer overhead on the rw mix: **{pct:.2f}%** "
-              f"({ok} the {r.get('acceptance_limit_pct', 5.0):.0f}% budget); "
-              f"enabled recording costs "
-              f"{r.get('enabled_overhead_pct_rw', 0.0):.1f}%.")
-
-
-def check_section(bench_path):
-    """§Correctness: the step.check overhead table from BENCH_check.json."""
-    r = json.load(open(bench_path))
-    print("\n### step.check overhead (benchmarks/BENCH_check.json)\n")
-    print("| workload | checker | seconds | ops/s | findings |")
-    print("|---|---|---|---|---|")
-    for wl, key in (("rw mix (S=8, 8 threads)", "rw"), ("logreg fit", "logreg")):
-        for state in ("noop", "disabled", "armed"):
-            row = r.get(f"{key}_{state}")
-            if row is None:
-                continue
-            ops = f"{row['ops_per_sec']:.0f}" if "ops_per_sec" in row else "—"
-            print(f"| {wl} | {state} | {row['seconds']:.4f} | {ops} | "
-                  f"{row['findings']} |")
-    pct = r.get("disabled_overhead_pct_rw")
-    if pct is not None:
-        ok = "within" if r.get("disabled_within_limit") else "OVER"
-        print(f"\nDisabled-checker overhead on the rw mix: **{pct:.2f}%** "
-              f"({ok} the {r.get('acceptance_limit_pct', 5.0):.0f}% budget); "
-              f"armed analysis costs "
-              f"{r.get('armed_overhead_pct_rw', 0.0):.1f}%.")
-
-
 def export_check_report(path):
     """Run the four analytics apps under an armed checker plus the seeded
     race from examples/race_demo.py, and export one findings JSON — the
@@ -187,13 +141,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--variant", default="baseline")
-    ap.add_argument("--trace-bench", default="benchmarks/BENCH_trace.json",
-                    help="step.trace overhead JSON (section skipped if absent)")
     ap.add_argument("--export-trace", default=None, metavar="PATH",
                     help="run a traced 2-thread logreg fit and write the "
                          "Perfetto-loadable trace JSON to PATH, then exit")
-    ap.add_argument("--check-bench", default="benchmarks/BENCH_check.json",
-                    help="step.check overhead JSON (section skipped if absent)")
     ap.add_argument("--export-check", default=None, metavar="PATH",
                     help="run the four analytics apps and a seeded race "
                          "under an armed checker and write the findings "
@@ -207,10 +157,6 @@ def main():
         return
     if not os.path.isdir(args.out):
         print(f"# no dry-run records at {args.out}; skipping dryrun/roofline")
-        if os.path.exists(args.trace_bench):
-            trace_section(args.trace_bench)
-        if os.path.exists(args.check_bench):
-            check_section(args.check_bench)
         return
     recs, skips = load(args.out)
 
@@ -246,11 +192,6 @@ def main():
                       f"{fmt_bytes(r['peak_bytes'])} | {improvement_note(r)} |")
             elif k in skips:
                 print(f"| {a} | {s} | — | — | — | skipped | — | — | — | {skips[k]['reason']} |")
-
-    if os.path.exists(args.trace_bench):
-        trace_section(args.trace_bench)
-    if os.path.exists(args.check_bench):
-        check_section(args.check_bench)
 
 
 if __name__ == "__main__":

@@ -4,8 +4,7 @@ Each exposes ``fit_reference`` (single-thread oracle) and ``fit`` — one
 backend-agnostic ``thread_proc`` over the `step.Session` facade that runs on
 either substrate: ``backend="host"`` (the paper's Pthreads-style DThread +
 DSM + accumulator programming model) or ``backend="spmd"`` (one STEP thread
-per mesh position via shard_map, the production path).  The pre-Session
-entry points ``fit_threads`` / ``fit_spmd`` remain as deprecation shims."""
+per mesh position via shard_map, the production path)."""
 
 from repro.analytics import kmeans, logreg, nmf, pagerank
 

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import AccumMode, Session
-from repro.core.session import SpmdBackend, deprecated_entry
 
 
 # f32 matmuls at full precision: a TPU's default rounds their inputs to bf16,
@@ -96,30 +95,3 @@ def fit(x, k: int, *, iters: int = 10, seed: int = 0,
 
     sess.run(thread_proc, data=(jnp.asarray(x),))
     return np.asarray(centers.get()), sess
-
-
-# ---------------------------------------------------------------------------
-# Deprecated pre-Session entry points
-# ---------------------------------------------------------------------------
-
-
-def fit_threads(x, k: int, *, n_nodes: int = 2, threads_per_node: int = 2,
-                iters: int = 10, seed: int = 0,
-                mode: AccumMode | str = AccumMode.REDUCE_SCATTER,
-                use_kernel: bool = False):
-    """Deprecated shim: ``fit(backend="host")`` with the old return tuple."""
-    deprecated_entry("kmeans.fit_threads", 'kmeans.fit(backend="host")')
-    sess = Session(backend="host", n_nodes=n_nodes,
-                   threads_per_node=threads_per_node, accum_mode=mode)
-    centers, sess = fit(x, k, iters=iters, seed=seed, mode=mode,
-                        use_kernel=use_kernel, session=sess)
-    return centers, sess.store, sess.accumulator("partials")
-
-
-def fit_spmd(x, k: int, mesh, *, iters: int = 10, seed: int = 0,
-             mode: AccumMode | str = AccumMode.REDUCE_SCATTER):
-    """Deprecated shim: ``fit(backend="spmd")``."""
-    deprecated_entry("kmeans.fit_spmd", 'kmeans.fit(backend="spmd")')
-    sess = Session(backend=SpmdBackend(mesh=mesh))
-    centers, _ = fit(x, k, iters=iters, seed=seed, mode=mode, session=sess)
-    return centers

@@ -15,8 +15,6 @@ margin is then a gather ``Σ_j θ[idx_j]·val_j`` and the gradient a
 scatter-add into a length-``n_features`` vector, so a mini-batch's gradient
 is as sparse as its rows.  ``batch=`` takes round ``i``'s rows
 ``[i·batch, (i+1)·batch)`` of each thread's partition.
-
-``fit_threads`` / ``fit_spmd`` remain as deprecation shims over ``fit``.
 """
 
 from __future__ import annotations
@@ -29,8 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import AccumMode, Session
-from repro.core.dsm import GlobalStore
-from repro.core.session import SpmdBackend, deprecated_entry
 
 
 def _sigmoid(z):
@@ -167,30 +163,3 @@ def fit_ssp(x, y, *, n_workers: int = 4, staleness: int = 1, iters: int = 10,
 
     sess.run(worker, data=(jnp.asarray(x), jnp.asarray(y)), timeout=60)
     return np.asarray(theta.get()), clock
-
-
-# ---------------------------------------------------------------------------
-# Deprecated pre-Session entry points
-# ---------------------------------------------------------------------------
-
-
-def fit_threads(x, y, *, n_nodes: int = 2, threads_per_node: int = 2,
-                iters: int = 10, lr: float = 1e-3,
-                mode: AccumMode | str = AccumMode.REDUCE_SCATTER,
-                store: Optional[GlobalStore] = None):
-    """Deprecated shim: ``fit(backend="host")`` with the old return tuple."""
-    deprecated_entry("logreg.fit_threads", 'logreg.fit(backend="host")')
-    sess = Session(backend="host", n_nodes=n_nodes,
-                   threads_per_node=threads_per_node, store=store,
-                   accum_mode=mode)
-    theta, sess = fit(x, y, iters=iters, lr=lr, mode=mode, session=sess)
-    return theta, sess.store, sess.accumulator("grad")
-
-
-def fit_spmd(x, y, mesh, *, iters: int = 10, lr: float = 1e-3,
-             mode: AccumMode | str = AccumMode.REDUCE_SCATTER, k: int = 0):
-    """Deprecated shim: ``fit(backend="spmd")``."""
-    deprecated_entry("logreg.fit_spmd", 'logreg.fit(backend="spmd")')
-    sess = Session(backend=SpmdBackend(mesh=mesh))
-    theta, _ = fit(x, y, iters=iters, lr=lr, mode=mode, k=k or None, session=sess)
-    return theta

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import AccumMode, Session
-from repro.core.session import SpmdBackend, deprecated_entry
 
 _EPS = 1e-9
 
@@ -83,30 +82,3 @@ def fit(r, k: int, *, iters: int = 10, seed: int = 0,
     ps = sess.run(thread_proc, data=(jnp.asarray(r), jnp.asarray(p_full0)))
     p_full = np.concatenate([np.asarray(p) for p in ps], axis=0)
     return p_full, np.asarray(Q.get()), sess
-
-
-# ---------------------------------------------------------------------------
-# Deprecated pre-Session entry points
-# ---------------------------------------------------------------------------
-
-
-def fit_threads(r, k: int, *, n_nodes: int = 2, threads_per_node: int = 2,
-                iters: int = 10, seed: int = 0,
-                mode: AccumMode | str = AccumMode.REDUCE_SCATTER,
-                store=None):
-    """Deprecated shim: ``fit(backend="host")`` with the old return tuple."""
-    deprecated_entry("nmf.fit_threads", 'nmf.fit(backend="host")')
-    sess = Session(backend="host", n_nodes=n_nodes,
-                   threads_per_node=threads_per_node, store=store,
-                   accum_mode=mode)
-    p, q, sess = fit(r, k, iters=iters, seed=seed, mode=mode, session=sess)
-    return p, q, sess.store, sess.accumulator("q_partials")
-
-
-def fit_spmd(r, k: int, mesh, *, iters: int = 10, seed: int = 0,
-             mode: AccumMode | str = AccumMode.REDUCE_SCATTER):
-    """Deprecated shim: ``fit(backend="spmd")``."""
-    deprecated_entry("nmf.fit_spmd", 'nmf.fit(backend="spmd")')
-    sess = Session(backend=SpmdBackend(mesh=mesh))
-    p, q, _ = fit(r, k, iters=iters, seed=seed, mode=mode, session=sess)
-    return p, q

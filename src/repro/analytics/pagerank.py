@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import AccumMode, Session
-from repro.core.session import SpmdBackend, deprecated_entry
 
 DAMPING = 0.85
 LANES = 128   # width of a row of the segmented sum: a TPU vector register's lanes
@@ -216,29 +215,3 @@ def fit(edges, n_vertices: int, *, iters: int = 10,
 
     sess.run(thread_proc, data=(jnp.asarray(edges),), broadcast=(out_deg,))
     return np.asarray(ranks.get()), sess
-
-
-# ---------------------------------------------------------------------------
-# Deprecated pre-Session entry points
-# ---------------------------------------------------------------------------
-
-
-def fit_threads(edges, n_vertices: int, *, n_nodes: int = 2,
-                threads_per_node: int = 2, iters: int = 10,
-                mode: AccumMode | str = AccumMode.AUTO):
-    """Deprecated shim: ``fit(backend="host")`` with the old return tuple."""
-    deprecated_entry("pagerank.fit_threads", 'pagerank.fit(backend="host")')
-    sess = Session(backend="host", n_nodes=n_nodes,
-                   threads_per_node=threads_per_node, accum_mode=mode)
-    ranks, sess = fit(edges, n_vertices, iters=iters, mode=mode, session=sess)
-    return ranks, sess.store, sess.accumulator("credits")
-
-
-def fit_spmd(edges, n_vertices: int, mesh, *, iters: int = 10,
-             mode: AccumMode | str = AccumMode.REDUCE_SCATTER, k: int = 0):
-    """Deprecated shim: ``fit(backend="spmd")``."""
-    deprecated_entry("pagerank.fit_spmd", 'pagerank.fit(backend="spmd")')
-    sess = Session(backend=SpmdBackend(mesh=mesh))
-    ranks, _ = fit(edges, n_vertices, iters=iters, mode=mode, k=k or None,
-                   session=sess)
-    return ranks

@@ -23,14 +23,18 @@ Two layers:
   the Pthreads-style thread pool.  It *accounts traffic per mode* so the
   ``(2N+1)·V → (N+1)·V`` claim is assertable in tests.
 
-Sparse parity contract (both layers): a contribution is compressed with the
-*same* :func:`~repro.core.sparse.blocked_topk_sparsify` dispatch (Pallas
-``topk_compress`` kernel, interpret mode off-TPU), the reduction sums the
-scattered pairs, and wire traffic is ``2 · pair_capacity(V, k)`` elements per
-contribution plus the ``V``-element republish — derived from the actual pair
-arrays, never from a dense sum with sparse accounting.  Compression is lossy
-iff some block's nnz exceeds its per-block quota; ``auto`` only selects pairs
-when they are lossless AND cheaper, so it never changes results.
+Sparse parity contract (both layers): a contribution keeps the *same*
+blocked top-k selection (:func:`~repro.core.sparse.block_layout`).  The SPMD
+collective compresses it with :func:`~repro.core.sparse.blocked_topk_sparsify`
+(Pallas ``topk_compress`` kernel, interpret mode off-TPU) and sums the
+scattered pairs; the host class sums the round in one fused sparsify→
+scatter-add launch (:func:`~repro.core.sparse.blocked_topk_accumulate`),
+bit-exact with that chain.  Wire traffic is ``2 · pair_capacity(V, k)``
+elements per contribution plus the ``V``-element republish — the static
+length of the pair arrays, never a dense sum with sparse accounting.
+Compression is lossy iff some block's nnz exceeds its per-block quota;
+``auto`` only selects pairs when they are lossless AND cheaper, so it never
+changes results.
 """
 
 from __future__ import annotations
@@ -200,14 +204,14 @@ class DAddAccumulator:
     :class:`~repro.core.dsm.GlobalStore`.  Traffic is accounted per the paper's
     cost model so unit tests can assert (N+1)·V vs (2N+1)·V.
 
-    ``mode=SPARSE`` needs a top-k budget ``k``: each thread's contribution is
-    compressed to :class:`~repro.core.sparse.SparsePairs` (the same Pallas
-    ``topk_compress`` dispatch the SPMD collective uses), the round sums the
-    scattered pairs, and traffic is ``Σ_threads 2·pairs + V`` from the actual
-    pair-array lengths.  ``mode=AUTO`` buffers the round, applies the paper's
-    benefit rule to every contribution (lossless AND cheaper), and takes the
-    pairs path only when all threads agree — mirroring the SPMD collective's
-    globally-agreed branch.  All contributions in a round must have the same
+    ``mode=SPARSE`` needs a top-k budget ``k``: the round's contributions are
+    stacked and summed by one fused blocked-top-k sparsify→scatter-add launch
+    (:func:`~repro.core.sparse.blocked_topk_accumulate`, the same selection
+    the SPMD collective's pairs use), and traffic is ``Σ_threads 2·pairs + V``
+    with ``pairs = pair_capacity(V, k)``.  ``mode=AUTO`` buffers the round,
+    applies the paper's benefit rule to every contribution (lossless AND
+    cheaper), and takes the pairs path only when all threads agree —
+    mirroring the SPMD collective's globally-agreed branch.  All contributions in a round must have the same
     shape; a ragged contribution raises ``ValueError``, aborts the barrier
     (parked peers get ``BrokenBarrierError``) and poisons the accumulator —
     subsequent rounds raise ``RuntimeError`` instead of publishing.
@@ -216,7 +220,7 @@ class DAddAccumulator:
     def __init__(self, store, output_name: str, n_threads: int, n_nodes: int,
                  mode: AccumMode | str = AccumMode.REDUCE_SCATTER, *,
                  k: Optional[int] = None, block: int = DEFAULT_BLOCK,
-                 fused: bool = True, tracer=None, checker=None):
+                 tracer=None, checker=None):
         self.store = store
         self.tracer = tracer if tracer is not None else telemetry.NULL_TRACER
         self.checker = checker if checker is not None else stepcheck.NULL_CHECKER
@@ -228,10 +232,6 @@ class DAddAccumulator:
             raise ValueError("sparse mode needs a top-k budget k")
         self.k = k                  # AUTO with k=None defaults per round (~V/4)
         self.block = block
-        # fused=True applies SPARSE/AUTO pairs rounds as one sparsify→
-        # scatter-add kernel launch (bit-exact, same wire accounting);
-        # fused=False keeps the historical compress→densify→add path
-        self.fused = fused
         self._owner = None          # memoised (ring_version, shard) of output
         self._lock = threading.Lock()
         self._vecs: list = []           # buffered contributions (SPARSE/AUTO)
@@ -287,10 +287,7 @@ class DAddAccumulator:
             wire = self.bytes_transferred - wire_before
             span.args = {"mode": mode.value, "vec_len": vec_len, "threads": self.n,
                          "pairs": sum(self.last_pair_counts), "wire_elements": wire}
-        if mode == AccumMode.SPARSE:
-            path = "fused" if self.fused else "sparse"
-        else:
-            path = "dense"
+        path = "fused" if mode == AccumMode.SPARSE else "dense"
         trc.count(f"accum.kernel_path.{path}")
         trc.count("accumulate.rounds")
         trc.count("accumulate.wire_elements", wire)
@@ -326,30 +323,18 @@ class DAddAccumulator:
                 mode = AccumMode.SPARSE if all_ok else AccumMode.REDUCE_SCATTER
             if mode == AccumMode.SPARSE:
                 tc = time.perf_counter() if tracing else 0.0
-                if self.fused:
-                    # one fused sparsify→scatter-add launch over the stacked
-                    # round — no pair arrays or dense intermediates; the
-                    # logical pair count is the static capacity either way
-                    # (under jit num_pairs always equals pair_capacity), so
-                    # wire accounting is unchanged.  Armed, the launch is the
-                    # `accumulate.fused` span, which waits for the sum.
-                    with span("accumulate-round", "accumulate.fused"):
-                        total = blocked_topk_accumulate(
-                            jnp.stack(flats), k, self.block).reshape(shape)
-                        if tracing:
-                            total.block_until_ready()
-                    self.last_pair_counts = (
-                        [pair_capacity(vec_len, k, self.block)] * self.n)
-                else:
-                    pairs = [blocked_topk_sparsify(f, k, self.block)
-                             for f in flats]
-                    # one scatter-add over the concatenated pair arrays — the
-                    # same "densify everything at once" the SPMD all-gather
-                    # path does
-                    total = densify(jnp.concatenate([p.idx for p in pairs]),
-                                    jnp.concatenate([p.vals for p in pairs]),
-                                    vec_len).reshape(shape)
-                    self.last_pair_counts = [p.num_pairs for p in pairs]
+                # one fused sparsify→scatter-add launch over the stacked
+                # round — no pair arrays or dense intermediates; the logical
+                # pair count is the static capacity (under jit num_pairs
+                # always equals pair_capacity).  Armed, the launch is the
+                # `accumulate.fused` span, which waits for the sum.
+                with span("accumulate-round", "accumulate.fused"):
+                    total = blocked_topk_accumulate(
+                        jnp.stack(flats), k, self.block).reshape(shape)
+                    if tracing:
+                        total.block_until_ready()
+                self.last_pair_counts = (
+                    [pair_capacity(vec_len, k, self.block)] * self.n)
                 if tracing:
                     trc.observe("accumulate.compress",
                                 (time.perf_counter() - tc) * 1e6)

@@ -14,8 +14,7 @@ Three STEP concepts are kept first-class:
 * **fine- vs coarse-grained DSM** (§5.1) — a *layout policy*.  ``coarse`` packs
   pytree leaves into 128-element-aligned flat *packages* (``pack_tree``), so a
   collective over the packed buffer moves few large aligned blocks; ``fine``
-  leaves every leaf as its own transfer.  The paper's Fig. 3 ablation is
-  reproduced structurally in ``benchmarks/bench_dsm_modes.py``.
+  leaves every leaf as its own transfer.
 * **host/device split** — between barriers the store owns the arrays (the KV
   store's role); inside a jitted step, state is threaded functionally and the
   store is only consulted for packing metadata.

@@ -445,18 +445,15 @@ class Backend(Protocol):
 
 
 class HostBackend:
-    """Today's paper-faithful path: DThreadPool + blocking DAddAccumulator."""
+    """Today's paper-faithful path: DThreadPool + blocking DAddAccumulator
+    (whose SPARSE/AUTO pairs rounds reduce in one fused sparsify→scatter-add
+    kernel launch)."""
 
     kind = "host"
 
-    def __init__(self, n_nodes: int = 2, threads_per_node: int = 2, *,
-                 fused: bool = True):
+    def __init__(self, n_nodes: int = 2, threads_per_node: int = 2):
         self.pool = DThreadPool(n_nodes, threads_per_node)
         self.run_barrier = DBarrier(self.pool.n_threads)
-        # SPARSE/AUTO rounds reduce through the fused sparsify→scatter-add
-        # kernel; set False to route new accumulators down the historical
-        # compress→densify→add path (bit-exact either way)
-        self.fused = fused
         self._accumulators: Dict[tuple, DAddAccumulator] = {}
         self._lock = threading.Lock()
 
@@ -501,7 +498,6 @@ class HostBackend:
             if accu is None:
                 accu = DAddAccumulator(session.store, name, self.n_threads,
                                        self.n_nodes, mode, k=k,
-                                       fused=self.fused,
                                        tracer=session.tracer,
                                        checker=session.checker)
                 self._accumulators[key] = accu
@@ -1239,8 +1235,3 @@ class Session:
         return (f"Session(backend={self.backend.kind}, "
                 f"threads={self.backend.n_threads}, names={self.names()})")
 
-
-def deprecated_entry(old: str, new: str) -> None:
-    """One-liner for the pre-Session entry points kept as shims."""
-    warnings.warn(f"{old} is deprecated; use {new}", DeprecationWarning,
-                  stacklevel=3)

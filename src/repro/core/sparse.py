@@ -15,8 +15,11 @@ This module is the *dispatching layer* shared by both backends:
   :mod:`repro.kernels.topk_compress` kernel by default (interpret-mode
   fallback off-TPU) and keeps the jnp formulation as a tested reference
   (``impl="jnp"``).  Both produce the same :class:`SparsePairs` format.
-* :class:`SparsePairs` is the one pair container used by the host
-  ``DAddAccumulator`` and the SPMD collective — its static length
+* :func:`blocked_topk_accumulate` is the host ``DAddAccumulator``'s pairs
+  reduce: the same selection fused with the scatter-add, one
+  :mod:`repro.kernels.accumulate.fused_scatter` launch per round.
+* :class:`SparsePairs` is the pair container of the SPMD collective
+  (:func:`blocked_topk_sparsify`, then :func:`densify`) — its static length
   (:func:`pair_capacity`) is what both backends' wire-traffic accounting is
   derived from.
 """
@@ -202,29 +205,21 @@ def _fused_accumulate_jnp(mat: jax.Array, nblocks: int, block_eff: int,
 
 
 def blocked_topk_accumulate(mat: jax.Array, k: int, block: int = DEFAULT_BLOCK,
-                            *, fused: bool = True,
-                            impl: str = "pallas") -> jax.Array:
+                            *, impl: str = "pallas") -> jax.Array:
     """Sum of the budget-``k`` blocked top-k compressions of each row of a
-    stacked (N, V) round — the accumulator's SPARSE/AUTO reduce.
+    stacked (N, V) round — the host accumulator's SPARSE/AUTO reduce.
 
-    ``fused=True`` (default) merges selection with application: one
-    :mod:`repro.kernels.accumulate.fused_scatter` launch, no pair arrays or
-    dense per-thread intermediates (``impl="jnp"`` keeps the pure-jnp
-    reference with the same selection + left-fold schedule).  ``fused=False``
-    reproduces the historical compress→densify→add path (one
-    :func:`blocked_topk_sparsify` per row, scatter-add of the concatenated
-    pairs) — kept as the comparison baseline.  All four routes produce
-    bit-exact identical results: selection is block-local with ties broken
-    toward the lower index, and the fused left-fold matches the scatter-add's
-    per-index association order.
+    Selection and application are merged: ``impl="pallas"`` (default) is one
+    :mod:`repro.kernels.accumulate.fused_scatter` launch, with no pair arrays
+    or dense per-thread intermediates; ``impl="jnp"`` is the pure-jnp
+    reference with the same selection + left-fold schedule.  Both routes
+    equal, bit for bit, a :func:`blocked_topk_sparsify` per row followed by
+    one :func:`densify` of the concatenated pairs: selection is block-local
+    with ties broken toward the lower index, and the left-fold matches the
+    scatter-add's per-index association order.
     """
-    n_rows, v = mat.shape
+    v = mat.shape[1]
     nblocks, block_eff, per_block = block_layout(v, k, block)
-    if not fused:
-        pairs = [blocked_topk_sparsify(mat[t], k, block, impl=impl)
-                 for t in range(n_rows)]
-        return densify(jnp.concatenate([p.idx for p in pairs]),
-                       jnp.concatenate([p.vals for p in pairs]), v)
     if impl == "pallas":
         from repro.kernels.accumulate.fused_scatter import fused_topk_scatter
         return fused_topk_scatter(mat, per_block=per_block, block_eff=block_eff)

@@ -1,12 +1,14 @@
-"""Pallas TPU kernels for the compute hot-spots (each: kernel.py + ops.py + ref.py).
+"""Pallas TPU kernels for the compute hot-spots.
 
 flash_attention — online-softmax attention, VMEM accumulator over KV tiles
-accumulate      — the DAddAccumulator's blocked local combine (STEP §5.2)
-topk_compress   — blocked top-k pairs (accumulator sparse mode)
-sparse_update   — scatter-add of pairs via one-hot MXU GEMM (receive side)
+accumulate      — fused_scatter: the host DAddAccumulator's pairs reduce,
+                  blocked top-k sparsify→scatter-add in one launch (§5.2)
+topk_compress   — blocked top-k pairs (the SPMD collective's sparse mode)
 kmeans_assign   — nearest-center assignment via distance GEMM (paper §6.5)
 ssd_scan        — Mamba2 SSD: chunk GEMMs + VMEM-carried recurrent state
 
-All validated on CPU with interpret=True against the ref.py oracles; compiled
-(Mosaic) lowering engages on a real TPU backend.
+Each package but ``accumulate`` is kernel.py + ops.py + ref.py; the fused
+reduce's jnp reference lives beside its dispatch in ``repro.core.sparse``.
+All are validated on CPU with interpret=True against their references;
+compiled (Mosaic) lowering engages on a real TPU backend.
 """

@@ -1,1 +1,1 @@
-from repro.kernels.accumulate import fused_scatter, kernel, ops, ref
+from repro.kernels.accumulate import fused_scatter

@@ -1,6 +1,6 @@
 """Fused sparsify→scatter-add — the accumulator's SPARSE reduce in one launch.
 
-The unfused host path materialises, per round, N pair arrays (one
+An unfused reduce would materialise, per round, N pair arrays (one
 ``topk_compress`` launch per thread) plus a dense scatter-add over their
 concatenation.  But the blocked top-k selection is *block-local*: whether an
 entry of block ``j`` survives depends only on block ``j``'s magnitudes.  So

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache at one fixed place per checkout.
 
-Entry points (``chip_smoke.py``, the examples, the benchmarks) call
+Entry points (``chip_smoke.py``, the examples, ``bench/run.py``) call
 :func:`enable_compile_cache` first thing in ``main``; importing this module
 changes nothing.  The next run of the same checkout must find what this one
 compiled, so the directory never comes from a temporary name, a process id

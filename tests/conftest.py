@@ -60,3 +60,18 @@ def run_subprocess_devices(code: str, n_devices: int = 8, timeout: int = 600) ->
                           capture_output=True, text=True)
     assert proc.returncode == 0, f"subprocess failed:\n{proc.stdout}\n{proc.stderr}"
     return proc.stdout
+
+
+def unfused_reduce(mat, k: int, block: int):
+    """Oracle for the accumulator's pairs reduce: one blocked top-k sparsify
+    per row of the stacked (N, V) round, then one scatter-add of the
+    concatenated pairs.  Returns ``(sum, pair_counts, wire_elements)``, the
+    wire being ``Σ 2·pairs + V`` as the accumulator accounts it."""
+    import jax.numpy as jnp
+    from repro.core.sparse import blocked_topk_sparsify, densify
+    v = mat.shape[1]
+    pairs = [blocked_topk_sparsify(row, k, block) for row in mat]
+    total = densify(jnp.concatenate([p.idx for p in pairs]),
+                    jnp.concatenate([p.vals for p in pairs]), v)
+    return (total, [p.num_pairs for p in pairs],
+            sum(p.wire_elements for p in pairs) + v)

@@ -7,15 +7,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import unfused_reduce
 from repro.core import AccumMode, DAddAccumulator, GlobalStore
-from repro.core.sparse import pair_capacity
+from repro.core.sparse import DEFAULT_BLOCK, pair_capacity
 
 
-def run_round(mode, vecs, n_nodes=2, k=None, fused=True):
+def run_round(mode, vecs, n_nodes=2, k=None):
     n = len(vecs)
     store = GlobalStore()
     store.new_array("out", (vecs[0].size,))
-    acc = DAddAccumulator(store, "out", n, n_nodes, mode, k=k, fused=fused)
+    acc = DAddAccumulator(store, "out", n, n_nodes, mode, k=k)
     ts = [threading.Thread(target=acc.accumulate, args=(v,)) for v in vecs]
     [t.start() for t in ts]
     [t.join(10) for t in ts]
@@ -64,19 +65,19 @@ def test_sparse_traffic_from_actual_pairs():
 
 
 def test_fused_reduce_matches_unfused_bitexact():
-    """fused=True (one sparsify→scatter-add kernel launch) must be bit-exact
-    with the historical compress→densify→add path, and carry identical pair
+    """The pairs round (one sparsify→scatter-add kernel launch) must be
+    bit-exact with the compress→densify→add chain, and carry identical pair
     counts + wire accounting — fusion is an implementation detail, never a
     semantics change."""
     V, N, k = 1024, 4, 8
     for vecs in (_sparse_vecs(V, N),                       # lossless round
                  [jnp.asarray(np.random.default_rng(i).normal(size=V)
                               .astype(np.float32)) for i in range(N)]):  # lossy
-        out_f, acc_f = run_round(AccumMode.SPARSE, vecs, k=k, fused=True)
-        out_u, acc_u = run_round(AccumMode.SPARSE, vecs, k=k, fused=False)
-        assert np.array_equal(out_f, out_u)
-        assert acc_f.last_pair_counts == acc_u.last_pair_counts
-        assert acc_f.bytes_transferred == acc_u.bytes_transferred
+        out_f, acc_f = run_round(AccumMode.SPARSE, vecs, k=k)
+        out_u, counts_u, wire_u = unfused_reduce(jnp.stack(vecs), k, DEFAULT_BLOCK)
+        assert np.array_equal(out_f, np.asarray(out_u))
+        assert acc_f.last_pair_counts == counts_u
+        assert acc_f.bytes_transferred == wire_u
 
 
 def test_sparse_requires_budget():

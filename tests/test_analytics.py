@@ -17,8 +17,9 @@ from repro.utils.hlo import op_scopes
 def test_logreg_threads_match_reference():
     x, y, _ = logreg_dataset(400, 24, seed=0)
     ref = logreg.fit_reference(x, y, iters=10, lr=1e-3)
-    th, store, accu = logreg.fit_threads(x, y, n_nodes=2, threads_per_node=2,
-                                         iters=10, lr=1e-3)
+    th, sess = logreg.fit(x, y, backend="host", n_nodes=2, threads_per_node=2,
+                          iters=10, lr=1e-3, mode=AccumMode.REDUCE_SCATTER)
+    accu = sess.accumulator("grad")
     np.testing.assert_allclose(th, ref, rtol=1e-4, atol=1e-5)
     assert accu.bytes_transferred == (4 + 1) * 24 * 10   # (N+1)·V per round
     assert logreg.loss(th, x, y) < logreg.loss(np.zeros(24, np.float32), x, y)
@@ -26,10 +27,12 @@ def test_logreg_threads_match_reference():
 
 def test_logreg_gather_all_traffic_is_higher():
     x, y, _ = logreg_dataset(200, 16, seed=1)
-    _, _, naive = logreg.fit_threads(x, y, n_nodes=2, threads_per_node=2,
-                                     iters=5, mode=AccumMode.GATHER_ALL)
-    _, _, rs = logreg.fit_threads(x, y, n_nodes=2, threads_per_node=2,
-                                  iters=5, mode=AccumMode.REDUCE_SCATTER)
+    _, sess_naive = logreg.fit(x, y, backend="host", n_nodes=2, threads_per_node=2,
+                               iters=5, mode=AccumMode.GATHER_ALL)
+    _, sess_rs = logreg.fit(x, y, backend="host", n_nodes=2, threads_per_node=2,
+                            iters=5, mode=AccumMode.REDUCE_SCATTER)
+    naive = sess_naive.accumulator("grad")
+    rs = sess_rs.accumulator("grad")
     assert naive.bytes_transferred == (2 * 4 + 1) * 16 * 5
     assert rs.bytes_transferred == (4 + 1) * 16 * 5
 
@@ -37,7 +40,8 @@ def test_logreg_gather_all_traffic_is_higher():
 def test_kmeans_threads_match_reference():
     x, _, _ = kmeans_dataset(600, 8, 5, seed=1)
     cr = kmeans.fit_reference(x, 5, iters=8, seed=1)
-    ct, _, _ = kmeans.fit_threads(x, 5, n_nodes=2, threads_per_node=2, iters=8, seed=1)
+    ct, _ = kmeans.fit(x, 5, backend="host", n_nodes=2, threads_per_node=2,
+                       iters=8, seed=1)
     np.testing.assert_allclose(np.sort(ct, axis=0), np.sort(cr, axis=0),
                                rtol=1e-3, atol=1e-3)
 
@@ -45,8 +49,8 @@ def test_kmeans_threads_match_reference():
 def test_kmeans_kernel_path():
     x, _, _ = kmeans_dataset(300, 8, 4, seed=2)
     cr = kmeans.fit_reference(x, 4, iters=5, seed=2)
-    ck, _, _ = kmeans.fit_threads(x, 4, n_nodes=1, threads_per_node=2, iters=5,
-                                  seed=2, use_kernel=True)
+    ck, _ = kmeans.fit(x, 4, backend="host", n_nodes=1, threads_per_node=2,
+                       iters=5, seed=2, use_kernel=True)
     np.testing.assert_allclose(np.sort(ck, axis=0), np.sort(cr, axis=0),
                                rtol=1e-3, atol=1e-3)
 
@@ -54,8 +58,8 @@ def test_kmeans_kernel_path():
 def test_nmf_threads_match_reference():
     r, _, _ = nmf_dataset(120, 32, 4, seed=2)
     pr, qr = nmf.fit_reference(r, 4, iters=10, seed=2)
-    pt, qt, _, _ = nmf.fit_threads(r, 4, n_nodes=2, threads_per_node=2,
-                                   iters=10, seed=2)
+    pt, qt, _ = nmf.fit(r, 4, backend="host", n_nodes=2, threads_per_node=2,
+                        iters=10, seed=2)
     np.testing.assert_allclose(nmf.frob_loss(r, pt, qt), nmf.frob_loss(r, pr, qr),
                                rtol=1e-2)
 
@@ -63,8 +67,8 @@ def test_nmf_threads_match_reference():
 def test_pagerank_threads_match_reference():
     edges = powerlaw_graph(300, 5, seed=3)
     rr = pagerank.fit_reference(edges, 300, iters=10)
-    rt, _, accu = pagerank.fit_threads(edges, 300, n_nodes=2, threads_per_node=2,
-                                       iters=10, mode=AccumMode.AUTO)
+    rt, _ = pagerank.fit(edges, 300, backend="host", n_nodes=2, threads_per_node=2,
+                         iters=10, mode=AccumMode.AUTO)
     np.testing.assert_allclose(rt, rr, rtol=1e-4, atol=1e-6)
     assert abs(float(np.sum(rr)) - 1.0) < 0.05  # ranks ≈ distribution
 
@@ -255,36 +259,6 @@ def test_spmd_pagerank_sorts_once_per_job_and_never_in_a_round():
     assert all(any(p.startswith("accumulate.") for p in s) for s in in_round), in_round
     assert not [s for s in scope.values()
                 if {"pagerank.gather", "pagerank.scatter"} & set(s)]
-
-
-def test_deprecated_shims_warn_and_stay_correct():
-    """fit_threads / fit_spmd are shims: they must warn DeprecationWarning AND
-    still return the same results as the fit() they forward to."""
-    from repro.core.compat import make_mesh
-    mesh1 = make_mesh((1,), ("data",))
-
-    x, y, _ = logreg_dataset(200, 16, seed=5)
-    ref_lr = logreg.fit_reference(x, y, iters=6, lr=1e-3)
-    with pytest.warns(DeprecationWarning, match="logreg.fit_threads"):
-        th, store, accu = logreg.fit_threads(x, y, n_nodes=2, threads_per_node=2,
-                                             iters=6, lr=1e-3)
-    np.testing.assert_allclose(th, ref_lr, rtol=1e-4, atol=1e-5)
-    assert accu.rounds == 6
-    with pytest.warns(DeprecationWarning, match="logreg.fit_spmd"):
-        th_s = logreg.fit_spmd(x, y, mesh1, iters=6, lr=1e-3)
-    np.testing.assert_allclose(th_s, ref_lr, rtol=1e-4, atol=1e-5)
-
-    xk, _, _ = kmeans_dataset(300, 8, 4, seed=6)
-    ref_km = kmeans.fit_reference(xk, 4, iters=5, seed=6)
-    with pytest.warns(DeprecationWarning, match="kmeans.fit_threads"):
-        ck, _, _ = kmeans.fit_threads(xk, 4, n_nodes=2, threads_per_node=2,
-                                      iters=5, seed=6)
-    np.testing.assert_allclose(np.sort(ck, axis=0), np.sort(ref_km, axis=0),
-                               rtol=1e-3, atol=1e-3)
-    with pytest.warns(DeprecationWarning, match="kmeans.fit_spmd"):
-        cs = kmeans.fit_spmd(xk, 4, mesh1, iters=5, seed=6)
-    np.testing.assert_allclose(np.sort(cs, axis=0), np.sort(ref_km, axis=0),
-                               rtol=1e-3, atol=1e-3)
 
 
 def test_logreg_ssp_async_converges():

@@ -5,6 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import unfused_reduce
+
 pytestmark = pytest.mark.kernel  # interpret-mode kernel tests, in tier-1
 
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -49,29 +51,35 @@ def test_flash_attention_gqa_layout():
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("n,v,bv", [(4, 1024, 256), (7, 3000, 512), (1, 128, 128)])
-def test_accumulate_sweep(dtype, n, v, bv):
-    from repro.kernels.accumulate.kernel import accumulate_blocked
-    from repro.kernels.accumulate.ref import accumulate_ref
+@pytest.mark.parametrize("n,v,block", [(4, 1024, 256), (7, 3000, 512), (1, 128, 128)])
+def test_fused_scatter_dense_round_matches_jnp(dtype, n, v, block):
+    """With the quota at the whole block the fused kernel is the dense N-way
+    sum: bit for bit the jnp reference (both fold in f32 and cast once)."""
+    from repro.core.sparse import _fused_accumulate_jnp, block_layout
+    from repro.kernels.accumulate.fused_scatter import fused_topk_scatter
+    nblocks, block_eff, _ = block_layout(v, v, block)
     rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.normal(size=(n, v)), dtype)
-    out = accumulate_blocked(x, block_v=bv, interpret=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(accumulate_ref(x), np.float32), **_tol(dtype))
+    mat = jnp.asarray(rng.normal(size=(n, v)), dtype)
+    got = fused_topk_scatter(mat, per_block=block_eff, block_eff=block_eff,
+                             interpret=True)
+    want = _fused_accumulate_jnp(mat, nblocks, block_eff, block_eff)
+    assert got.dtype == dtype
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(mat, np.float32).sum(axis=0), **_tol(dtype))
 
 
 @pytest.mark.parametrize("v,k,bv", [(900, 4, 256), (2048, 16, 512), (100, 2, 64)])
 def test_topk_compress_sweep(v, k, bv):
     from repro.kernels.topk_compress.kernel import topk_compress_blocked
     from repro.kernels.topk_compress.ref import topk_compress_ref
-    from repro.kernels.sparse_update.ref import sparse_scatter_add_ref
+    from repro.core.sparse import densify
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(v,)), jnp.float32)
     idx, vals = topk_compress_blocked(x, k_per_block=k, block_v=bv, interpret=True)
     ridx, rvals = topk_compress_ref(x, k_per_block=k, block_v=bv)
-    np.testing.assert_allclose(
-        np.asarray(sparse_scatter_add_ref(idx, vals, v)),
-        np.asarray(sparse_scatter_add_ref(ridx, rvals, v)), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(densify(idx, vals, v)),
+                               np.asarray(densify(ridx, rvals, v)), rtol=1e-6)
 
 
 @pytest.mark.parametrize("v,k,bv", [(900, 4, 256), (2048, 16, 512), (100, 2, 64),
@@ -121,17 +129,17 @@ def test_topk_method_auto_selection():
 ])
 def test_fused_scatter_bitexact_vs_unfused(n, v, k, block):
     """The fused sparsify→scatter-add must be *bit-exact* against the
-    compress→densify→add path it replaces, for both impls, across densities
-    (dense rounds, realistic sparse rounds, all-zero rounds)."""
+    compress→densify→add chain, for both impls, across densities (dense
+    rounds, realistic sparse rounds, all-zero rounds)."""
     from repro.core.sparse import blocked_topk_accumulate
     rng = np.random.default_rng(9)
     for density in (0.0, 0.01, 0.3, 1.0):
         mat = rng.normal(size=(n, v)).astype(np.float32)
         mat[rng.random((n, v)) >= density] = 0.0
         mat = jnp.asarray(mat)
-        ref = blocked_topk_accumulate(mat, k, block, fused=False)
-        fused = blocked_topk_accumulate(mat, k, block, fused=True, impl="pallas")
-        fused_jnp = blocked_topk_accumulate(mat, k, block, fused=True, impl="jnp")
+        ref, _, _ = unfused_reduce(mat, k, block)
+        fused = blocked_topk_accumulate(mat, k, block, impl="pallas")
+        fused_jnp = blocked_topk_accumulate(mat, k, block, impl="jnp")
         assert np.array_equal(np.asarray(ref), np.asarray(fused)), density
         assert np.array_equal(np.asarray(ref), np.asarray(fused_jnp)), density
 
@@ -165,28 +173,6 @@ def test_fused_scatter_kernel_validation():
         fused_topk_scatter(jnp.zeros((8,)), per_block=2, block_eff=8)
     with pytest.raises(ValueError, match="per_block"):
         fused_topk_scatter(jnp.zeros((2, 8)), per_block=0, block_eff=8)
-
-
-@pytest.mark.parametrize("m,v,bv", [(50, 700, 256), (200, 4096, 1024), (1, 64, 64)])
-def test_scatter_add_sweep(m, v, bv):
-    from repro.kernels.sparse_update.kernel import sparse_scatter_add
-    from repro.kernels.sparse_update.ref import sparse_scatter_add_ref
-    rng = np.random.default_rng(4)
-    idx = jnp.asarray(rng.integers(0, v, size=(m,)), jnp.int32)
-    vals = jnp.asarray(rng.normal(size=(m,)), jnp.float32)
-    out = sparse_scatter_add(idx, vals, v, block_v=bv, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(sparse_scatter_add_ref(idx, vals, v)),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_scatter_add_duplicates():
-    from repro.kernels.sparse_update.kernel import sparse_scatter_add
-    idx = jnp.asarray([3, 3, 3, 0], jnp.int32)
-    vals = jnp.asarray([1.0, 2.0, 3.0, 5.0], jnp.float32)
-    out = sparse_scatter_add(idx, vals, 8, block_v=8, interpret=True)
-    np.testing.assert_allclose(np.asarray(out)[3], 6.0)
-    np.testing.assert_allclose(np.asarray(out)[0], 5.0)
 
 
 @pytest.mark.parametrize("n,k,d,bn", [(500, 11, 24, 128), (1000, 3, 8, 256)])

@@ -59,9 +59,13 @@ def test_accumulator_linearity(seed):
     rng = np.random.default_rng(seed)
     a = rng.normal(size=(64,)).astype(np.float32)
     b = rng.normal(size=(64,)).astype(np.float32)
-    from repro.kernels.accumulate.ref import accumulate_ref
-    lhs = accumulate_ref(jnp.stack([jnp.asarray(a + b)]))
-    rhs = accumulate_ref(jnp.stack([jnp.asarray(a)])) + accumulate_ref(jnp.stack([jnp.asarray(b)]))
+    from repro.core.sparse import blocked_topk_accumulate
+
+    def total(*rows):        # k = 64 fills the one 64-wide block: no selection
+        return blocked_topk_accumulate(jnp.stack([jnp.asarray(r) for r in rows]), k=64)
+
+    lhs = total(a + b)
+    rhs = total(a) + total(b)
     np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs), rtol=1e-5)
 
 

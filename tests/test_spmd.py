@@ -70,11 +70,12 @@ from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh(data=4)
 x, y, _ = logreg_dataset(400, 24, seed=0)
 ref = logreg.fit_reference(x, y, iters=8, lr=1e-3)
-sp = logreg.fit_spmd(x, y, mesh, iters=8, lr=1e-3)
+sp, _ = logreg.fit(x, y, iters=8, lr=1e-3, mesh=mesh, backend="spmd")
 np.testing.assert_allclose(sp, ref, rtol=1e-4, atol=1e-5)
 edges = powerlaw_graph(300, 5, seed=3)
 rr = pagerank.fit_reference(edges, 300, iters=8)
-rs = pagerank.fit_spmd(edges, 300, mesh, iters=8)
+rs, _ = pagerank.fit(edges, 300, iters=8, mode="reduce_scatter", mesh=mesh,
+                     backend="spmd")
 np.testing.assert_allclose(rs, rr, rtol=1e-4, atol=1e-6)
 print("ANALYTICS_SPMD_OK")
 """, n_devices=4)

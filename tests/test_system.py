@@ -50,8 +50,8 @@ def test_thread_pool_failure_recovery_end_to_end():
     x, _, _ = kmeans_dataset(400, 8, 4, seed=0)
     with tempfile.TemporaryDirectory() as d:
         # phase 1: run 4 iters, checkpoint
-        c1, _, _ = kmeans.fit_threads(x, 4, n_nodes=2, threads_per_node=2,
-                                      iters=4, seed=0)
+        c1, _ = kmeans.fit(x, 4, backend="host", n_nodes=2, threads_per_node=2,
+                           iters=4, seed=0)
         save_checkpoint(d, 4, {"centers": c1})
         # failure + recovery: resume on a SMALLER pool from the checkpoint
         restored, _, _ = restore_checkpoint(d, {"centers": c1})
